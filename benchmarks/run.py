@@ -61,6 +61,8 @@ def main() -> None:
                          "trace JSON as Perfetto counter tracks")
     args = ap.parse_args()
     quick = not args.full
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     # monotonic clock for elapsed time (immune to wall-clock steps);
     # wall-clock start is recorded separately in the manifest
     t_start = time.perf_counter()

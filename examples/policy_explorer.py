@@ -140,6 +140,8 @@ def main() -> None:
                          "also land in the trace as Perfetto counter "
                          "tracks")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     if args.list_policies:
         from repro.fleet import (autoscaler_names, fleet_preset_names,

@@ -41,8 +41,8 @@ from .rules import RULES
 
 #: Primitive names that run code on host mid-program.
 HOST_CALLBACK_PRIMS = frozenset({
-    "debug_callback", "pure_callback", "io_callback", "callback",
-    "infeed", "outfeed", "host_callback_call",
+    "debug_callback", "debug_print", "pure_callback", "io_callback",
+    "callback", "infeed", "outfeed", "host_callback_call",
 })
 
 #: Tiny audit shape — tracing cost only, results never executed.
@@ -59,8 +59,7 @@ def _jax():
 # --------------------------------------------------------------------------
 
 def _sub_jaxprs(params: dict):
-    from jax.core import Jaxpr
-    from jax.extend.core import ClosedJaxpr  # type: ignore
+    from jax.extend.core import ClosedJaxpr, Jaxpr
     for v in params.values():
         vals = v if isinstance(v, (list, tuple)) else (v,)
         for item in vals:

@@ -999,19 +999,22 @@ def _cache_key(policy: PolicySpec, cluster: ClusterCfg,
                backend: str,
                telemetry: TelemetryCfg | None = None,
                chunk: int | None = None,
-               timeline: TimelineCfg | None = None) -> tuple:
+               timeline: TimelineCfg | None = None,
+               mesh=None) -> tuple:
     # telemetry-on engines trace a different program, so the cfg is part
     # of the key (None = the golden pre-telemetry program).  ``chunk``
     # marks a streaming chunk engine (the chunk size IS the key's shape
     # axis — n_arrivals then holds the chunk length, and one compiled
     # program serves any horizon); None = monolithic.  ``timeline``
     # likewise gates a different traced program (the flight-recorder
-    # plane), so its cfg joins the key as a trailing element.
+    # plane), so its cfg joins the key as a trailing element, as does
+    # the device mesh of a rep-sharded streaming engine.
     return (tuple(policy), tuple(cluster), int(n_arrivals),
             int(n_functions), batched, backend,
             None if telemetry is None else tuple(telemetry),
             None if chunk is None else int(chunk),
-            None if timeline is None else tuple(timeline))
+            None if timeline is None else tuple(timeline),
+            mesh)
 
 
 def _cache_get_or_build(key: tuple, build):
@@ -1097,7 +1100,8 @@ def _get_engine(policy: PolicySpec, cluster: ClusterCfg,
 def _get_stream_engine(policy: PolicySpec, cluster: ClusterCfg,
                        chunk: int, n_functions: int, backend: str,
                        telemetry: TelemetryCfg | None,
-                       timeline: TimelineCfg | None = None):
+                       timeline: TimelineCfg | None = None,
+                       mesh=None):
     """Cached streaming chunk-engine lookup.
 
     Returns ``((init, step_fn, drain_fn), fresh)``.  ``step_fn`` is the
@@ -1106,6 +1110,8 @@ def _get_stream_engine(policy: PolicySpec, cluster: ClusterCfg,
     boundaries reuses its device buffers instead of copying them;
     ``drain_fn`` donates the same way.  The key carries the chunk size
     instead of the horizon — growing ``N`` reuses one compiled program.
+    With a 1-D ``"rep"`` ``mesh`` both programs run per device on its
+    block of replications (:func:`repro.distribution.sim_shard.rep_local`).
     """
     if chunk < 1:
         raise ValueError(f"chunk_size must be >= 1, got {chunk}")
@@ -1113,7 +1119,7 @@ def _get_stream_engine(policy: PolicySpec, cluster: ClusterCfg,
     backend = _resolve_backend(policy, backend)
     key = _cache_key(policy, cluster, int(chunk), n_functions, True,
                      backend, telemetry, chunk=int(chunk),
-                     timeline=timeline)
+                     timeline=timeline, mesh=mesh)
 
     def build():
         init, run_chunk, run_drain = _build_engine(
@@ -1121,10 +1127,15 @@ def _get_stream_engine(policy: PolicySpec, cluster: ClusterCfg,
             telemetry=telemetry, timeline=timeline, stream=True)
         # carry batched over reps; gids/valid unbatched so the padding
         # cond keeps a scalar predicate (a real branch, not a select)
-        step_fn = jax.jit(
-            jax.vmap(run_chunk, in_axes=(0, None, None, 0, 0, 0, 0, 0)),
-            donate_argnums=(0,))
-        drain_fn = jax.jit(jax.vmap(run_drain), donate_argnums=(0,))
+        batched = (0, None, None, 0, 0, 0, 0, 0)
+        step = jax.vmap(run_chunk, in_axes=batched)
+        drain = jax.vmap(run_drain)
+        if mesh is not None:
+            from repro.distribution.sim_shard import rep_local
+            step = rep_local(step, mesh, batched)
+            drain = rep_local(drain, mesh, (0,))
+        step_fn = jax.jit(step, donate_argnums=(0,))
+        drain_fn = jax.jit(drain, donate_argnums=(0,))
         return (init, step_fn, drain_fn)
 
     return _cache_get_or_build(key, build)

@@ -140,7 +140,7 @@ def simulate_stream(policy: PolicySpec, cluster: ClusterCfg,
     k = int(chunk_size)
     N, F, R = wb.n, wb.n_functions, wb.n_reps
     (init, step_fn, drain_fn), fresh = _get_stream_engine(
-        policy, cluster, k, F, backend, telemetry, timeline)
+        policy, cluster, k, F, backend, telemetry, timeline, mesh=mesh)
     cutoff = warmup_cutoff(N, telemetry)
     # the runtime window width is horizon-dependent (auto = horizon/K),
     # so it is computed host-side per replication and written into the

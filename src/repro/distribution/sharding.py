@@ -38,28 +38,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 AxisVal = Any  # str | tuple[str, ...] | None
 
 
-def shard_map_compat(f, mesh, in_specs, out_specs, *, axis_names=None,
-                     check_vma=False):
-    """``jax.shard_map`` across JAX versions.
-
-    Newer JAX exposes ``jax.shard_map(..., axis_names=manual_axes,
-    check_vma=...)``; 0.4.x has ``jax.experimental.shard_map.shard_map(...,
-    auto=non_manual_axes, check_rep=...)``.  ``axis_names=None`` means all
-    mesh axes are manual (both APIs' default).
-    """
-    if hasattr(jax, "shard_map"):
-        kw = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=check_vma)
-        if axis_names is not None:
-            kw["axis_names"] = axis_names
-        return jax.shard_map(f, **kw)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    kw = dict(check_rep=check_vma)
-    if axis_names is not None:
-        kw["auto"] = frozenset(mesh.axis_names) - frozenset(axis_names)
-    return _shard_map(f, mesh, in_specs, out_specs, **kw)
-
-
 @dataclasses.dataclass(frozen=True)
 class ShardCtx:
     mesh: Mesh
@@ -95,21 +73,6 @@ def sharding_ctx(ctx: ShardCtx):
     try:
         with ctx.mesh:
             yield ctx
-    finally:
-        _ctx.reset(tok)
-
-
-@contextlib.contextmanager
-def no_sharding_ctx():
-    """Suspend logical-axis constraints (``shard()`` becomes a no-op).
-
-    Used inside partial-manual ``shard_map`` regions on older JAX, where
-    inner ``with_sharding_constraint``s over the auto axes trip an XLA
-    manual-subgroup check; GSPMD then auto-shards the region instead.
-    """
-    tok = _ctx.set(None)
-    try:
-        yield
     finally:
         _ctx.reset(tok)
 

@@ -8,9 +8,13 @@ cells) are independent, so the batched carry of
 ``R`` axis across a 1-D ``"rep"`` mesh
 (:func:`repro.launch.mesh.make_rep_mesh`).  Every leaf of the carry and
 every per-chunk input is placed with ``NamedSharding(mesh, P("rep",
-None, ...))``; the chunk program is already vmapped over that axis, so
-XLA partitions the scan across devices with no cross-device
-communication (replications never interact).
+None, ...))``, and the chunk and drain programs run under
+:func:`rep_local`: a ``shard_map`` over that axis, so each device scans
+its own block of replications with no cross-device communication
+(replications never interact).  Left to GSPMD instead, the vmapped
+completion loop's predicate (any replication still busy) becomes an
+all-reduce per iteration, and a Pallas kernel in the scan (Hermes)
+cannot be partitioned at all.
 
 Unbatched operands (the global-id / valid-mask vectors, whose cond
 predicates must stay scalar) are left alone — jit replicates them.
@@ -27,6 +31,19 @@ REP_AXIS = "rep"
 def rep_sharding(mesh, ndim: int) -> NamedSharding:
     """Sharding for one leaf: leading axis over ``"rep"``, rest replicated."""
     return NamedSharding(mesh, P(REP_AXIS, *([None] * (ndim - 1))))
+
+
+def rep_local(fn, mesh, in_axes):
+    """Run ``fn`` per device on its block of the replication axis.
+
+    ``in_axes`` gives, per positional argument, ``0`` for an operand
+    batched over reps (every leaf leads with the rep axis) or ``None``
+    for one that every device sees whole; every output is rep-batched.
+    """
+    spec = lambda ax: P() if ax is None else P(REP_AXIS)
+    return jax.shard_map(fn, mesh=mesh,
+                         in_specs=tuple(spec(ax) for ax in in_axes),
+                         out_specs=P(REP_AXIS), check_vma=False)
 
 
 def shard_reps(tree, mesh):

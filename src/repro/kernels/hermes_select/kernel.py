@@ -28,39 +28,60 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
 _BIG = 1 << 30
+# strong int32 constants: under x64 a Python-int literal is a weak int64
+# scalar, whose conversion the chip's kernel compiler cannot lower
+_NEG = np.int32(-_BIG)
+_NONE = np.int32(-1)
 
 
-def _kernel(active_ref, warm_ref, out_ref, active_out_ref, act_ref,
+def _kernel(active_ref, warm_ref, out_ref, active_out_ref,
             *, n: int, cores: int, slots: int):
-    act_ref[...] = active_ref[...]                    # [1, W] int32
+    W = active_ref.shape[1]
+    lanes_w = jax.lax.broadcasted_iota(jnp.int32, (1, W), 1)
+    lanes_n = jax.lax.broadcasted_iota(jnp.int32, (1, n), 1)
 
-    def body(i, _):
-        active = act_ref[0]                           # [W]
-        warm = warm_ref[i] > 0                        # [W] bool
+    def body(i, carry):
+        active, choices = carry                       # [1, W], [1, N] i32
+        warm_i = (warm_ref[pl.ds(i, 1), :] > 0).astype(jnp.int32)
         has_slot = active < slots
         has_core = active < cores
-        nonempty = active > 0
-        warm_i = warm.astype(jnp.int32)
-        cls = jnp.where(nonempty, 2 + warm_i, warm_i)
-        lo = jnp.where(has_core, cls * (slots + 1) + active, -_BIG)
-        hi = jnp.where(has_slot, -(active * 2 - warm_i), -_BIG)
-        score = jnp.where(has_core.any(), lo, hi)
-        w = jnp.argmax(score).astype(jnp.int32)
-        ok = has_slot.any()
-        out_ref[i] = jnp.where(ok, w, -1)
-        act_ref[0] = jnp.where(
-            ok & (jax.lax.iota(jnp.int32, active.shape[0]) == w),
-            active + 1, active)
-        return _
+        cls = jnp.where(active > 0, 2 + warm_i, warm_i)
+        lo = jnp.where(has_core, cls * (slots + 1) + active, _NEG)
+        hi = jnp.where(has_slot, -(active * 2 - warm_i), _NEG)
+        # the chip reduces in f32 only; every score is an integer far
+        # below 2**24 and -_BIG = -2**30 is exact, so the f32 max and
+        # the min of the masked lane index are numpy's first argmax
+        low_mode = jnp.max(has_core.astype(jnp.float32)) > 0
+        score = jnp.where(low_mode, lo, hi).astype(jnp.float32)
+        top = jnp.max(score)
+        w = jnp.min(jnp.where(score == top, lanes_w.astype(jnp.float32),
+                              jnp.float32(W))).astype(jnp.int32)
+        ok = jnp.max(has_slot.astype(jnp.float32)) > 0
+        # choices stay a vector (no scalar store to VMEM); one store at
+        # the end
+        choices = jnp.where(lanes_n == i, jnp.where(ok, w, _NONE), choices)
+        active = jnp.where(ok & (lanes_w == w), active + 1, active)
+        return active, choices
 
-    # strong-typed bounds/carry: Python-int literals would thread a
-    # weak int64 carry through the loop (repro.analysis JXP001)
-    jax.lax.fori_loop(jnp.int32(0), jnp.int32(n), body, jnp.int32(0))
-    active_out_ref[...] = act_ref[...]
+    # strong-typed bounds: Python-int literals would thread a weak int64
+    # loop index through the loop (repro.analysis JXP001)
+    active, choices = jax.lax.fori_loop(
+        jnp.int32(0), jnp.int32(n), body,
+        (active_ref[...], jnp.full((1, n), _NONE, dtype=jnp.int32)))
+    out_ref[...] = choices
+    active_out_ref[...] = active
+
+
+def interpret_mode() -> bool:
+    """Whether the kernel runs in the Pallas interpreter: off a TPU only.
+
+    A CPU-only convenience for tests; on a TPU the kernel is compiled.
+    """
+    return jax.default_backend() != "tpu"
 
 
 def hermes_select_batch(active, warm_cols, *, cores: int, slots: int,
@@ -72,19 +93,23 @@ def hermes_select_batch(active, warm_cols, *, cores: int, slots: int,
     function on each worker (gathered by the caller from ``warm[W, F]``).
 
     Returns (choices [N] int32 — worker ids or -1, active_out [W]).
+    Every operand is at least 2-D with full-array blocks, so under
+    ``vmap`` (the scan engine's replication axis) the batched blocks
+    still meet the chip's (8, 128) tiling rule.
     """
     W = active.shape[0]
     N = warm_cols.shape[0]
     kernel = functools.partial(_kernel, n=N, cores=cores, slots=slots)
+    # full-array blocks; int32 block indices (a Python 0 is int64 under
+    # x64, which the kernel compiler refuses once vmap adds a grid axis)
+    zero = lambda: (np.int32(0), np.int32(0))
     out, active_out = pl.pallas_call(
         kernel,
-        in_specs=[pl.BlockSpec((1, W), lambda: (0, 0)),
-                  pl.BlockSpec((N, W), lambda: (0, 0))],
-        out_specs=[pl.BlockSpec((N,), lambda: (0,)),
-                   pl.BlockSpec((1, W), lambda: (0, 0))],
-        out_shape=[jax.ShapeDtypeStruct((N,), jnp.int32),
+        in_specs=[pl.BlockSpec((1, W), zero), pl.BlockSpec((N, W), zero)],
+        out_specs=[pl.BlockSpec((1, N), zero), pl.BlockSpec((1, W), zero)],
+        out_shape=[jax.ShapeDtypeStruct((1, N), jnp.int32),
                    jax.ShapeDtypeStruct((1, W), jnp.int32)],
-        scratch_shapes=[pltpu.VMEM((1, W), jnp.int32)],
         interpret=interpret,
+        name="hermes_select",
     )(active[None], warm_cols)
-    return out, active_out[0]
+    return out[0], active_out[0]

@@ -6,11 +6,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from .kernel import hermes_select_batch
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+from .kernel import hermes_select_batch, interpret_mode
 
 
 @partial(jax.jit, static_argnames=("cores", "slots"))
@@ -19,4 +15,4 @@ def hermes_select(active, warm, funcs, *, cores: int, slots: int):
     warm_cols = warm.T[funcs].astype(jnp.int32)       # [N, W]
     return hermes_select_batch(active.astype(jnp.int32), warm_cols,
                                cores=cores, slots=slots,
-                               interpret=_interpret())
+                               interpret=interpret_mode())

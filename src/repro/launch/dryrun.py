@@ -192,8 +192,6 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool,
                               donate_argnums=donate).lower(*arg_shapes)
             compiled = lowered.compile()
             ca = compiled.cost_analysis() or {}
-            if isinstance(ca, (list, tuple)):  # jax<=0.4.x: list per device
-                ca = ca[0] if ca else {}
             ma = compiled.memory_analysis()
             res.status = "ok"
             res.flops = float(ca.get("flops", 0.0))

@@ -8,29 +8,14 @@ is 16×16 = 256 chips (one v5e pod); multi-pod adds a leading ``pod`` axis
 from __future__ import annotations
 
 import jax
-
-try:  # jax >= 0.5: explicit axis types on mesh construction
-    from jax.sharding import AxisType
-except ImportError:  # older jax (e.g. 0.4.x): no AxisType / axis_types kwarg
-    AxisType = None
+from jax.sharding import AxisType
 
 from repro.distribution.sharding import ShardCtx, make_rules
 
 
 def _mesh(shape, axes):
-    """``jax.make_mesh`` across JAX versions.
-
-    Newer JAX takes ``axis_types``; we always want ``Auto`` (the implicit
-    default of older versions), so on a JAX without ``AxisType`` plain
-    construction is semantically identical.
-    """
-    if AxisType is not None:
-        try:
-            return jax.make_mesh(shape, axes,
-                                 axis_types=(AxisType.Auto,) * len(axes))
-        except TypeError:  # AxisType exists but make_mesh predates the kwarg
-            pass
-    return jax.make_mesh(shape, axes)
+    """``jax.make_mesh`` with every axis ``Auto`` (GSPMD-partitioned)."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

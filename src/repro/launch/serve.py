@@ -127,6 +127,8 @@ def main() -> None:
                          "the windows also land in the trace JSON as "
                          "Perfetto counter tracks")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     if args.backend == "models":
         from repro import configs

@@ -26,7 +26,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.distribution.sharding import shard, shard_map_compat
+from repro.distribution.sharding import shard
 from repro.models.layers import apply_rope, dense_init, rmsnorm
 
 NEG_INF = -1e30
@@ -337,8 +337,8 @@ def _flash_decode_sharded(qg, k_cache, v_cache, pos, scale, axes):
         o = jax.lax.psum(o.astype(jnp.float32), axes)
         return (o / jnp.maximum(l, 1e-30)[..., None]).astype(qg.dtype)
 
-    return shard_map_compat(
-        local, mesh,
+    return jax.shard_map(
+        local, mesh=mesh,
         in_specs=(P(dp, None, None, None), P(dp, axes, None, None),
                   P(dp, axes, None, None), P(dp)),
         out_specs=P(dp, None, None, None),
@@ -653,8 +653,8 @@ def _mla_flash_decode_sharded(q_lat, q_rope, c_kv_cache, k_rope_cache,
         o = jax.lax.psum(o.astype(jnp.float32), axes)
         return (o / jnp.maximum(l, 1e-30)[..., None]).astype(ql.dtype)
 
-    return shard_map_compat(
-        local, mesh,
+    return jax.shard_map(
+        local, mesh=mesh,
         in_specs=(P(dp, None, None), P(dp, None, None),
                   P(dp, axes, None), P(dp, axes, None), P(dp)),
         out_specs=P(dp, None, None),

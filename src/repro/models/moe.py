@@ -26,8 +26,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.distribution.sharding import (current_ctx, shard,
-                                         shard_map_compat)
+from repro.distribution.sharding import current_ctx, shard
 from repro.models.layers import dense_init
 
 
@@ -187,8 +186,8 @@ def moe_ep(cfg, p, x):
 
     wspec_df = P(tp, fsdp, None)   # [E, D, F] experts over model (+fsdp on D)
     wspec_fd = P(tp, None, fsdp)
-    y, aux = shard_map_compat(
-        local, ctx.mesh,
+    y, aux = jax.shard_map(
+        local, mesh=ctx.mesh,
         in_specs=(P(dp, tp, None), P(None, None),
                   wspec_df, wspec_df, wspec_fd),
         out_specs=(P(dp, tp, None), P()),

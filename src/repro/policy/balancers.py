@@ -545,10 +545,10 @@ def _swarm_jax(cores: int, slots: int):
 # --------------------------------------------------------------------------
 
 def _hybrid_pallas(cores: int, slots: int):
-    import jax
     import jax.numpy as jnp
-    from repro.kernels.hermes_select.kernel import hermes_select_batch
-    interpret = jax.default_backend() != "tpu"
+    from repro.kernels.hermes_select.kernel import (hermes_select_batch,
+                                                    interpret_mode)
+    interpret = interpret_mode()
 
     def select(active, warm_col, func, func_home, u, idx):
         # N=1 batch: the sequential contract is preserved exactly — the

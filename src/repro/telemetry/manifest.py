@@ -7,8 +7,9 @@ from the tracer's span aggregate.  ``benchmarks.run`` attaches one to
 every ``BENCH_report.json`` so a figure can always be traced back to the
 exact code + environment that drew it.
 
-Everything here degrades gracefully: no git checkout, no jax install,
-no tracer — the corresponding fields just read ``None``/empty.
+Git is optional (no checkout: the git fields read ``None``).  The device
+is not: a failing ``jax.devices()`` raises, so no report is ever drawn
+without naming the platform and device kind it ran on.
 """
 from __future__ import annotations
 
@@ -26,9 +27,11 @@ class RunManifest:
     git_dirty: bool | None
     python: str
     platform: str
-    jax_version: str | None
-    numpy_version: str | None
+    jax_version: str
+    numpy_version: str
     devices: list[str]
+    device_platform: str     # jax.devices()[0].platform, e.g. "tpu"
+    device_kind: str         # jax.devices()[0].device_kind
     started_at: str
     duration_s: float | None = None
     seeds: dict = dataclasses.field(default_factory=dict)
@@ -55,29 +58,20 @@ def _git(*argv: str) -> str | None:
 def collect(seeds: Mapping[str, Any] | None = None,
             args: Mapping[str, Any] | None = None) -> RunManifest:
     """Snapshot provenance at run start; fill timing/cache fields later."""
-    jax_version = None
-    devices: list[str] = []
-    try:
-        import jax
-        jax_version = jax.__version__
-        devices = [str(d) for d in jax.devices()]
-    except Exception:
-        pass
-    numpy_version = None
-    try:
-        import numpy
-        numpy_version = numpy.__version__
-    except Exception:
-        pass
+    import jax
+    import numpy
+    devices = jax.devices()
     dirty = _git("status", "--porcelain")
     return RunManifest(
         git_sha=_git("rev-parse", "HEAD"),
         git_dirty=None if dirty is None else bool(dirty),
         python=sys.version.split()[0],
         platform=platform.platform(),
-        jax_version=jax_version,
-        numpy_version=numpy_version,
-        devices=devices,
+        jax_version=jax.__version__,
+        numpy_version=numpy.__version__,
+        devices=[str(d) for d in devices],
+        device_platform=devices[0].platform,
+        device_kind=devices[0].device_kind,
         started_at=datetime.datetime.now(
             datetime.timezone.utc).isoformat(timespec="seconds"),
         seeds=dict(seeds or {}),

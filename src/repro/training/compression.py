@@ -32,9 +32,7 @@ def ef_compress_sync(grads, err, axis: str):
     grads/err: pytrees of per-pod gradient leaves (fp32 math).
     Returns (synced_grads_mean, new_err).
     """
-    # jax.lax.axis_size is newer-JAX; psum(1) is the portable spelling
-    n = (jax.lax.axis_size(axis) if hasattr(jax.lax, "axis_size")
-         else jax.lax.psum(1, axis))
+    n = jax.lax.axis_size(axis)
 
     def one(g, e):
         if g.size == 0:            # placeholder leaves (e.g. no-op norms)

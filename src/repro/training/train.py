@@ -22,7 +22,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.distribution.sharding import current_ctx, pspec, shard_map_compat
+from repro.distribution.sharding import current_ctx, pspec
 from repro.training.compression import ef_compress_sync, init_error_feedback
 from repro.training.optimizer import (OptCfg, OptState, adamw_update,
                                       init_opt_state)
@@ -111,21 +111,8 @@ def build_train_step_compressed(model, opt_cfg: OptCfg, *,
     inner_ctx = ShardCtx(mesh=mesh, rules=inner_rules, dp_axes=("data",),
                          tp_axis=ctx.tp_axis, pod_axis=None)
 
-    # Partial-manual shard_map on jax<0.5 (no jax.shard_map) trips an XLA
-    # manual-subgroup check when sharding constraints or a layer-scan
-    # appear under grad inside the auto region.  There: suspend the inner
-    # constraints (GSPMD places the region; semantics unchanged) and
-    # unroll the layer stack (identical params/math, scan-free HLO).
-    from repro.distribution.sharding import no_sharding_ctx
-    if hasattr(jax, "shard_map"):
-        _inner_scope = lambda: sharding_ctx(inner_ctx)     # noqa: E731
-    else:
-        from repro.models.transformer import build_model
-        model = build_model(model.cfg, layer_mode="unroll")
-        _inner_scope = no_sharding_ctx
-
     def local(state: TrainState, tokens, labels):
-        with _inner_scope():              # trace-time rebinding
+        with sharding_ctx(inner_ctx):     # trace-time rebinding
             loss, grads = _accum_grads(model.loss, state.params, tokens,
                                        labels, microbatches)
             grads, new_err = ef_compress_sync(grads, state.err, pod)
@@ -143,8 +130,8 @@ def build_train_step_compressed(model, opt_cfg: OptCfg, *,
                           err=rep)
     batch_spec = P(pod)
     metric_sp = {"grad_norm": P(), "lr": P(), "loss": P()}
-    return shard_map_compat(
-        local, mesh,
+    return jax.shard_map(
+        local, mesh=mesh,
         in_specs=(state_sp, batch_spec, batch_spec),
         out_specs=(state_sp, metric_sp),
         axis_names={pod}, check_vma=False)

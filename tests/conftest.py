@@ -14,8 +14,13 @@ SRC = os.path.join(REPO, "src")
 
 def run_devices_script(body: str, n_devices: int = 8,
                        timeout: int = 560) -> str:
-    """Run a Python snippet in a fresh process with N host devices."""
+    """Run a Python snippet in a fresh process with N host devices.
+
+    The child is pinned to the CPU: a parent that holds an accelerator
+    cannot hand it to a child.
+    """
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
                         + f" --xla_force_host_platform_device_count={n_devices}")
     env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")

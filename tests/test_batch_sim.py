@@ -345,11 +345,13 @@ def test_stream_requires_early_binding():
 @pytest.mark.slow
 def test_stream_sharded_reps_match_unsharded(devices_script):
     """Rep-axis device sharding changes placement, not results: the
-    sharded run is bit-equal to the single-device run, and a rep count
-    that does not divide the mesh raises the named error."""
+    sharded run is bit-equal to the single-device run (the Hermes
+    kernel included), its carry lives on every device of the mesh, and
+    a rep count that does not divide the mesh raises the named error."""
     devices_script("""
+import jax
 import numpy as np
-from repro.core import ClusterCfg, E_DD_PS, synth_workload
+from repro.core import ClusterCfg, E_DD_PS, HERMES, synth_workload
 from repro.telemetry import TelemetryCfg
 from repro.core.streaming import final_states_equal, simulate_stream
 from repro.launch.mesh import make_rep_mesh
@@ -359,14 +361,20 @@ tel = TelemetryCfg()
 wls = [synth_workload(cl, 0.8, 120, n_functions=5, seed=s)
        for s in range(4)]
 mesh = make_rep_mesh(4)
-a = simulate_stream(E_DD_PS, cl, wls, chunk_size=40, telemetry=tel,
-                    keep_final_state=True)
-b = simulate_stream(E_DD_PS, cl, wls, chunk_size=40, telemetry=tel,
-                    keep_final_state=True, mesh=mesh)
-ok, bad = final_states_equal(a.final_state, b.final_state)
-assert ok, bad
-np.testing.assert_array_equal(a.n_done, b.n_done)
-np.testing.assert_array_equal(a.resp_mean, b.resp_mean)
+for pol in (E_DD_PS, HERMES):
+    a = simulate_stream(pol, cl, wls, chunk_size=40, telemetry=tel,
+                        keep_final_state=True, collect_outputs=True)
+    b = simulate_stream(pol, cl, wls, chunk_size=40, telemetry=tel,
+                        keep_final_state=True, collect_outputs=True,
+                        mesh=mesh)
+    ok, bad = final_states_equal(a.final_state, b.final_state)
+    assert ok, bad
+    np.testing.assert_array_equal(a.worker, b.worker)
+    np.testing.assert_array_equal(a.n_done, b.n_done)
+    np.testing.assert_array_equal(a.resp_mean, b.resp_mean)
+    spread = {len(x.sharding.device_set)
+              for x in jax.tree_util.tree_leaves(b.final_state)}
+    assert spread == {4}, spread
 try:
     simulate_stream(E_DD_PS, cl, wls[:3], chunk_size=40, telemetry=tel,
                     mesh=mesh)

@@ -65,6 +65,7 @@ def test_quickstart_example_runs():
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(repo, "src")
+    env["JAX_PLATFORMS"] = "cpu"   # a parent holding a chip cannot share it
     p = subprocess.run(
         [sys.executable, os.path.join(repo, "examples", "quickstart.py"),
          "--quick"], capture_output=True, text=True, timeout=560, env=env)

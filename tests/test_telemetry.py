@@ -256,6 +256,20 @@ def test_manifest_collects():
               "devices", "started_at", "seeds", "args"):
         assert k in d, k
     assert d["seeds"] == {"base": 0}
+    import jax
+    assert d["device_platform"] == jax.devices()[0].platform
+    assert d["device_kind"] == jax.devices()[0].device_kind
+
+
+def test_manifest_does_not_hide_a_failed_device_query(monkeypatch):
+    import jax
+
+    def broken():
+        raise RuntimeError("TPU backend failed to start")
+
+    monkeypatch.setattr(jax, "devices", broken)
+    with pytest.raises(RuntimeError, match="failed to start"):
+        collect_manifest()
 
 
 def test_engine_cache_sees_telemetry():
