@@ -1,0 +1,11 @@
+"""Device time of the completion drain per scan step, in microseconds:
+the operations whose innermost named scope is ``advance`` (the drain
+loop, less the telemetry, timeline and lifecycle updates nested in it),
+as a share of the leaf operations' time in the slice inside a unit's
+scan, times ``step_device_us``
+(:func:`chipbench.program_trace.step_scopes`)."""
+from chipbench.program_trace import scope_us
+
+
+def read(run):
+    return scope_us(run, "advance")

@@ -26,6 +26,8 @@ import dataclasses
 
 import numpy as np
 
+from repro.telemetry.spans import get_tracer
+
 
 @dataclasses.dataclass(frozen=True)
 class Summary:
@@ -194,41 +196,44 @@ def summarize_batch(response: np.ndarray, service: np.ndarray,
     Per-replication :class:`Summary` rows use the same warmup handling as
     :func:`summarize`; the pooled summary treats the R × N tasks (after
     per-replication warmup drop) as one population and time-weights the
-    utilization integrals by each replication's horizon.
+    utilization integrals by each replication's horizon.  With the
+    process-wide tracer on, the work is a ``metrics.summarize_batch``
+    span.
     """
     R = response.shape[0]
-    per_rep = tuple(
-        summarize(response[r], service[r], cold[r], rejected[r],
-                  float(server_time[r]), float(core_time[r]),
-                  float(end_time[r]), warmup_frac=warmup_frac)
-        for r in range(R))
+    with get_tracer().span("metrics.summarize_batch", reps=R):
+        per_rep = tuple(
+            summarize(response[r], service[r], cold[r], rejected[r],
+                      float(server_time[r]), float(core_time[r]),
+                      float(end_time[r]), warmup_frac=warmup_frac)
+            for r in range(R))
 
-    n = response.shape[1]
-    lo = int(n * warmup_frac)
-    sel = np.ones((R, n), dtype=bool)
-    sel[:, :lo] = False
-    ok = sel & ~rejected & np.isfinite(response)
-    resp = response[ok]
-    svc = np.maximum(service[ok], 1e-12)
-    slow = resp / svc
-    horizon = max(float(np.sum(end_time)), 1e-12)
+        n = response.shape[1]
+        lo = int(n * warmup_frac)
+        sel = np.ones((R, n), dtype=bool)
+        sel[:, :lo] = False
+        ok = sel & ~rejected & np.isfinite(response)
+        resp = response[ok]
+        svc = np.maximum(service[ok], 1e-12)
+        slow = resp / svc
+        horizon = max(float(np.sum(end_time)), 1e-12)
 
-    def pct(x, q):
-        return float(np.percentile(x, q)) if len(x) else float("nan")
+        def pct(x, q):
+            return float(np.percentile(x, q)) if len(x) else float("nan")
 
-    pooled = Summary(
-        n=int(ok.sum()),
-        n_rejected=int((rejected & sel).sum()),
-        cold_frac=float(cold[ok].mean()) if ok.any() else float("nan"),
-        lat_p50=pct(resp, 50), lat_p99=pct(resp, 99),
-        slow_p50=pct(slow, 50), slow_p99=pct(slow, 99),
-        slow_mean=float(slow.mean()) if len(slow) else float("nan"),
-        mean_servers=float(np.sum(server_time)) / horizon,
-        mean_cores=float(np.sum(core_time)) / horizon,
-        throughput=float(np.isfinite(response).sum()) / horizon,
-    )
-    return BatchSummary(per_rep=per_rep, pooled=pooled,
-                        stats=_stats_over(per_rep))
+        pooled = Summary(
+            n=int(ok.sum()),
+            n_rejected=int((rejected & sel).sum()),
+            cold_frac=float(cold[ok].mean()) if ok.any() else float("nan"),
+            lat_p50=pct(resp, 50), lat_p99=pct(resp, 99),
+            slow_p50=pct(slow, 50), slow_p99=pct(slow, 99),
+            slow_mean=float(slow.mean()) if len(slow) else float("nan"),
+            mean_servers=float(np.sum(server_time)) / horizon,
+            mean_cores=float(np.sum(core_time)) / horizon,
+            throughput=float(np.isfinite(response).sum()) / horizon,
+        )
+        return BatchSummary(per_rep=per_rep, pooled=pooled,
+                            stats=_stats_over(per_rep))
 
 
 def summarize_batch_sim(out, wb, **kw) -> BatchSummary:

@@ -100,7 +100,7 @@ from repro.lifecycle import resolve_lifecycle
 from repro.policy import default_backend, resolve
 from repro.telemetry import engine as tel_engine
 from repro.telemetry import timeline_engine as tln_engine
-from repro.telemetry.spans import get_tracer
+from repro.telemetry.spans import get_tracer, op_scopes
 from repro.telemetry.state import (TelemetryCfg, TelemetryResult,
                                    warmup_cutoff)
 from repro.telemetry.timeline import (EV_AUTOSCALE, EV_MODE_FLIP,
@@ -113,6 +113,11 @@ from .workload import Workload, WorkloadBatch, stack_workloads
 
 EPS = 1e-9
 _BIG_TIME = 1e18
+#: the ``jax.named_scope`` names of a scan step's parts: the completion
+#: drain, the balancer's select, placement, the container lifecycle,
+#: and the telemetry and timeline planes' updates (set in their own
+#: modules).  Scopes nest; an operation belongs to the innermost.
+STEP_SCOPES = ("advance", "select", "place", "lifecycle", "tel", "tl")
 
 
 class SimState(NamedTuple):
@@ -226,7 +231,7 @@ def _build_engine(policy: PolicySpec, cluster: ClusterCfg,
                   backend: str = "jax",
                   telemetry: TelemetryCfg | None = None,
                   timeline: TimelineCfg | None = None,
-                  stream: bool = False):
+                  stream: bool = False, count_drain: bool = False):
     """Build the raw (un-jitted) scan engine for (policy, cluster, N, F).
 
     ``backend`` selects how worker selection dispatches (``"jax"`` or
@@ -267,6 +272,13 @@ def _build_engine(policy: PolicySpec, cluster: ClusterCfg,
     Every op a chunk step executes on the carry is the same op the
     monolithic scan executes at that arrival, so the handoff is
     bit-exact (the REPRO-CHECK contract gated by ``benchmarks``).
+
+    ``count_drain`` adds one int32 per scan step: the iterations of the
+    completion drain before that step's arrival.  ``run`` then returns
+    ``(st, iterations)`` (``[N]``) and ``run_chunk``'s ``ys`` gain them
+    as a fourth element (``0`` on padded steps).  Off, the traced
+    program is the uncounted one.  The parts of a step run under the
+    ``jax.named_scope`` names of :data:`STEP_SCOPES`.
     """
     W, C, S = cluster.n_workers, cluster.cores, cluster.slots
     F = n_functions
@@ -354,6 +366,7 @@ def _build_engine(policy: PolicySpec, cluster: ClusterCfg,
             r = r * speed_arr[:, None]
         return r
 
+    @jax.named_scope("place")
     def place(st: SimState, tid, w, f, svc_nom, t_arr):
         """Place arrival ``tid`` (fn ``f``, nominal service ``svc_nom``,
         arrival time ``t_arr``) on worker ``w`` (must be valid).
@@ -365,35 +378,36 @@ def _build_engine(policy: PolicySpec, cluster: ClusterCfg,
         active_w = (st.task_idx[w] >= 0).sum()
         life = st.life
         if life_on:
-            # lifecycle masks (mirroring LifecycleRuntime): only
-            # *materialized* pools (inside their pre-warm + keep-alive
-            # window) serve warm hits, occupy memory (slot pressure /
-            # budget) and are eviction candidates.  The victim is the
-            # LRU materialized pool — oldest idle-since, first index on
-            # ties, the tie-breaking contract shared with the oracle
-            lu = life["idle_since"]
-            pre, keep = life["pre"], life["keep"]
-            ages_w = st.now - lu[w, :F]
-            mat_w = (ages_w >= pre) & (ages_w <= pre + keep)
-            eff = jnp.where(mat_w, st.warm[w, :F], 0)
-            warm_cnt = eff[f]
-            is_cold = warm_cnt == 0
-            idle = eff.sum()
-            need_evict = is_cold & (active_w + idle >= S)
-            victim = jnp.argmin(jnp.where(eff > 0, lu[w, :F], jnp.inf))
-            pen_f = penalty if life_costs is None else life_costs[f]
-            if life_observe is not None:
-                # observe the placed pool's idle age AFTER the
-                # warm/cold decision (LifecycleRuntime.observe_place);
-                # virgin pools (idle_since < 0) are masked out
-                seen = lu[w, f] >= 0.0
-                gap = jnp.maximum(st.now - lu[w, f], 0.0)
-                ka_new = life_observe(life["ka"], f, gap)
-                ka = jax.tree_util.tree_map(
-                    lambda a, b: jnp.where(seen, a, b), ka_new,
-                    life["ka"])
-                pre2, keep2 = life_windows(ka)
-                life = dict(life, ka=ka, pre=pre2, keep=keep2)
+            with jax.named_scope("lifecycle"):
+                # lifecycle masks (mirroring LifecycleRuntime): only
+                # *materialized* pools (inside their pre-warm + keep-alive
+                # window) serve warm hits, occupy memory (slot pressure /
+                # budget) and are eviction candidates.  The victim is the
+                # LRU materialized pool — oldest idle-since, first index on
+                # ties, the tie-breaking contract shared with the oracle
+                lu = life["idle_since"]
+                pre, keep = life["pre"], life["keep"]
+                ages_w = st.now - lu[w, :F]
+                mat_w = (ages_w >= pre) & (ages_w <= pre + keep)
+                eff = jnp.where(mat_w, st.warm[w, :F], 0)
+                warm_cnt = eff[f]
+                is_cold = warm_cnt == 0
+                idle = eff.sum()
+                need_evict = is_cold & (active_w + idle >= S)
+                victim = jnp.argmin(jnp.where(eff > 0, lu[w, :F], jnp.inf))
+                pen_f = penalty if life_costs is None else life_costs[f]
+                if life_observe is not None:
+                    # observe the placed pool's idle age AFTER the
+                    # warm/cold decision (LifecycleRuntime.observe_place);
+                    # virgin pools (idle_since < 0) are masked out
+                    seen = lu[w, f] >= 0.0
+                    gap = jnp.maximum(st.now - lu[w, f], 0.0)
+                    ka_new = life_observe(life["ka"], f, gap)
+                    ka = jax.tree_util.tree_map(
+                        lambda a, b: jnp.where(seen, a, b), ka_new,
+                        life["ka"])
+                    pre2, keep2 = life_windows(ka)
+                    life = dict(life, ka=ka, pre=pre2, keep=keep2)
         else:
             warm_cnt = st.warm[w, f]
             is_cold = warm_cnt == 0
@@ -452,7 +466,8 @@ def _build_engine(policy: PolicySpec, cluster: ClusterCfg,
 
         return lax.while_loop(cond, body, st)
 
-    def advance(st: SimState, dt, funcs, services, arrivals) -> SimState:
+    @jax.named_scope("advance")
+    def advance(st: SimState, dt, funcs, services, arrivals):
         """Fast-forward the cluster by ``dt`` seconds of wall time.
 
         One completion is processed per iteration — the earliest-finishing
@@ -463,10 +478,13 @@ def _build_engine(policy: PolicySpec, cluster: ClusterCfg,
         the per-iteration update touches O(1) state instead of scattering
         over the whole ``[W, S]`` matrix (the engine's hot path at large
         ``W``).
+
+        Returns ``(st, iterations)``: the loop's iteration count (int32)
+        with ``count_drain``, else ``None``.
         """
 
         def cond(carry):
-            st, dt_left = carry
+            st, dt_left = carry[0], carry[1]
             active = st.task_idx >= 0
             # a tie can be left pending when a completion lands exactly on
             # the window edge — drain it before yielding to the caller
@@ -550,36 +568,37 @@ def _build_engine(policy: PolicySpec, cluster: ClusterCfg,
             f_pad = jnp.where(completed, f_j, F)
             life = st.life
             if life_on:
-                # mirror LifecycleRuntime.on_complete: zero a stale
-                # pool before the increment (expired executors must not
-                # resurrect), refresh the idle clock, then enforce the
-                # max_idle budget by LRU eviction over the worker's
-                # materialized pools
-                lu = life["idle_since"]
-                pre, keep = life["pre"], life["keep"]
-                age_j = now - lu[wj, f_j]
-                stale = age_j > pre[f_j] + keep[f_j]
-                base = jnp.where(stale, 0, st.warm[wj, f_j])
-                warm = st.warm.at[w_pad, f_pad].set(
-                    jnp.where(completed, base + 1,
-                              st.warm[w_pad, f_pad]).astype(jnp.int32))
-                lu = lu.at[w_pad, f_pad].set(
-                    jnp.where(completed, now, lu[w_pad, f_pad]))
-                life = dict(life, idle_since=lu)
-                if life_max_idle > 0:
-                    ages_row = now - lu[wj, :F]
-                    mat_row = (ages_row >= pre) & (ages_row <= pre + keep)
-                    eff = jnp.where(mat_row, warm[wj, :F], 0)
-                    over = completed & (eff.sum() > life_max_idle)
-                    evict = jnp.argmin(jnp.where(eff > 0, lu[wj, :F],
-                                                 jnp.inf))
-                    warm = warm.at[jnp.where(over, wj, 0),
-                                   jnp.where(over, evict, F)].add(
-                        -over.astype(jnp.int32))
-                    if tel_on:
-                        tel = tel_engine.on_evict(tel, over)
-                    if tl_on:
-                        tl = tln_engine.on_evict(tl, now, over)
+                with jax.named_scope("lifecycle"):
+                    # mirror LifecycleRuntime.on_complete: zero a stale
+                    # pool before the increment (expired executors must not
+                    # resurrect), refresh the idle clock, then enforce the
+                    # max_idle budget by LRU eviction over the worker's
+                    # materialized pools
+                    lu = life["idle_since"]
+                    pre, keep = life["pre"], life["keep"]
+                    age_j = now - lu[wj, f_j]
+                    stale = age_j > pre[f_j] + keep[f_j]
+                    base = jnp.where(stale, 0, st.warm[wj, f_j])
+                    warm = st.warm.at[w_pad, f_pad].set(
+                        jnp.where(completed, base + 1,
+                                  st.warm[w_pad, f_pad]).astype(jnp.int32))
+                    lu = lu.at[w_pad, f_pad].set(
+                        jnp.where(completed, now, lu[w_pad, f_pad]))
+                    life = dict(life, idle_since=lu)
+                    if life_max_idle > 0:
+                        ages_row = now - lu[wj, :F]
+                        mat_row = (ages_row >= pre) & (ages_row <= pre + keep)
+                        eff = jnp.where(mat_row, warm[wj, :F], 0)
+                        over = completed & (eff.sum() > life_max_idle)
+                        evict = jnp.argmin(jnp.where(eff > 0, lu[wj, :F],
+                                                     jnp.inf))
+                        warm = warm.at[jnp.where(over, wj, 0),
+                                       jnp.where(over, evict, F)].add(
+                            -over.astype(jnp.int32))
+                        if tel_on:
+                            tel = tel_engine.on_evict(tel, over)
+                        if tl_on:
+                            tl = tln_engine.on_evict(tl, now, over)
             else:
                 warm = st.warm.at[w_pad, f_pad].add(
                     completed.astype(jnp.int32))
@@ -628,17 +647,23 @@ def _build_engine(policy: PolicySpec, cluster: ClusterCfg,
                     + jnp.where(rec, slow_v, 0.0)))
             return st, dt_left - tau
 
-        st, _ = lax.while_loop(cond, body, (st, dt))
+        if count_drain:
+            st, _, n_iter = lax.while_loop(
+                cond, lambda c: body(c[:2]) + (c[2] + 1,),
+                (st, dt, jnp.int32(0)))
+        else:
+            (st, _), n_iter = lax.while_loop(cond, body, (st, dt)), None
         if late:
             st = pop_all(st, funcs, services, arrivals)
-        return st
+        return st, n_iter
 
     def early_arrival(st: SimState, tid, t_i, f_i, u_i, svc_i,
                       funcs, services, arrivals, homes):
         """Advance to ``t_i`` and run the early-binding select/place for
         arrival ``tid`` — the one shared body of the monolithic and
         stream steps, so chunked ≡ monolithic holds by construction.
-        Returns ``(st, w, is_cold)``.
+        Returns ``(st, w, is_cold, iterations)``, the last from
+        :func:`advance`.
         """
         if auto_on:
             # provisioned-time integral over [now, t_i] at the current
@@ -656,18 +681,19 @@ def _build_engine(policy: PolicySpec, cluster: ClusterCfg,
                 else jnp.float64(W)
             st = st._replace(tl=tln_engine.on_prov(
                 st.tl, st.now, (t_i - st.now) * n_prov * TL_CORES))
-        st = advance(st, t_i - st.now, funcs, services, arrivals)
+        st, n_iter = advance(st, t_i - st.now, funcs, services, arrivals)
         st = st._replace(now=t_i)
         active = (st.task_idx >= 0).sum(axis=1).astype(jnp.int32)
         if life_on:
-            # selection sees the materialized warm column (pools in
-            # their pre-warm phase or past their window are
-            # invisible) — mirrors LifecycleRuntime.materialized_col
-            lu = st.life["idle_since"]
-            pre, keep = st.life["pre"], st.life["keep"]
-            ages = st.now - lu[:, f_i]
-            m = (ages >= pre[f_i]) & (ages <= pre[f_i] + keep[f_i])
-            wcol = jnp.where(m, st.warm[:, f_i], 0)
+            with jax.named_scope("lifecycle"):
+                # selection sees the materialized warm column (pools in
+                # their pre-warm phase or past their window are
+                # invisible) — mirrors LifecycleRuntime.materialized_col
+                lu = st.life["idle_since"]
+                pre, keep = st.life["pre"], st.life["keep"]
+                ages = st.now - lu[:, f_i]
+                m = (ages >= pre[f_i]) & (ages <= pre[f_i] + keep[f_i])
+                wcol = jnp.where(m, st.warm[:, f_i], 0)
         else:
             wcol = st.warm[:, f_i]
         sel_active = active
@@ -717,12 +743,13 @@ def _build_engine(policy: PolicySpec, cluster: ClusterCfg,
                     st.tl, new_mode != st.tl["mode"], t_i,
                     EV_MODE_FLIP, new_mode, jnp.float64(np.nan)))
                 st = st._replace(tl=dict(st.tl, mode=new_mode))
-        if stateful:
-            w, lb = select(st.lb, sel_active, wcol, f_i, homes,
-                           u_i, tid)
-            st = st._replace(lb=lb)
-        else:
-            w = select(sel_active, wcol, f_i, homes, u_i, tid)
+        with jax.named_scope("select"):
+            if stateful:
+                w, lb = select(st.lb, sel_active, wcol, f_i, homes,
+                               u_i, tid)
+                st = st._replace(lb=lb)
+            else:
+                w = select(sel_active, wcol, f_i, homes, u_i, tid)
         if not stream:
             st = st._replace(rejected=st.rejected.at[tid].set(w < 0))
         if tel_on:
@@ -742,22 +769,23 @@ def _build_engine(policy: PolicySpec, cluster: ClusterCfg,
                                 t_i),
                 lambda s: s, st)
             is_cold = jnp.bool_(False)
-        return st, w, is_cold
+        return st, w, is_cold, n_iter
 
     if stream:
         def step(st: SimState, xs, funcs, services, arrivals, homes):
             gid, valid, t_i, f_i, u_i, svc_i = xs
 
             def live(s):
-                s, w, is_cold = early_arrival(
+                s, w, is_cold, n_iter = early_arrival(
                     s, gid, t_i, f_i, u_i, svc_i, funcs, services,
                     arrivals, homes)
-                return s, (w < 0, is_cold,
-                           jnp.where(w >= 0, w, -1).astype(jnp.int32))
+                ys = (w < 0, is_cold,
+                      jnp.where(w >= 0, w, -1).astype(jnp.int32))
+                return s, ys + ((n_iter,) if count_drain else ())
 
             def skip(s):
-                return s, (jnp.bool_(False), jnp.bool_(False),
-                           jnp.int32(-1))
+                ys = (jnp.bool_(False), jnp.bool_(False), jnp.int32(-1))
+                return s, ys + ((jnp.int32(0),) if count_drain else ())
 
             # ``valid`` masks the padded tail of the last chunk.  It is
             # passed unbatched under vmap, so the predicate stays
@@ -774,7 +802,8 @@ def _build_engine(policy: PolicySpec, cluster: ClusterCfg,
                     st = st._replace(tl=tln_engine.on_prov(
                         st.tl, st.now,
                         (t_i - st.now) * jnp.float64(W) * TL_CORES))
-                st = advance(st, t_i - st.now, funcs, services, arrivals)
+                st, n_iter = advance(st, t_i - st.now, funcs, services,
+                                     arrivals)
                 st = st._replace(now=t_i)
                 if tl_on:
                     st = st._replace(tl=tln_engine.on_arrival(
@@ -790,10 +819,10 @@ def _build_engine(policy: PolicySpec, cluster: ClusterCfg,
                         i.astype(jnp.int32)), q_tail=st.q_tail + 1)
                 st = lax.cond(active.min() < C, do_place, do_queue, st)
             else:
-                st, _, _ = early_arrival(st, i, t_i, f_i, u_i,
-                                         services[i], funcs, services,
-                                         arrivals, homes)
-            return st, ()
+                st, _, _, n_iter = early_arrival(
+                    st, i, t_i, f_i, u_i, services[i], funcs, services,
+                    arrivals, homes)
+            return st, (n_iter if count_drain else ())
 
     def init_planes():
         """Initial lb/life/tel/fleet/tl carry pytrees (shared between
@@ -865,11 +894,12 @@ def _build_engine(policy: PolicySpec, cluster: ClusterCfg,
                 else arrivals[N - 1] / jnp.float64(TL_K)
             st = st._replace(tl=dict(st.tl, window_s=ws))
         xs = (jnp.arange(N, dtype=jnp.int64), arrivals, funcs, u_lb)
-        st, _ = lax.scan(
+        st, iters = lax.scan(
             partial(step, funcs=funcs, services=services, arrivals=arrivals,
                     homes=homes), st, xs)
         t_last = st.now
-        st = advance(st, jnp.float64(_BIG_TIME), funcs, services, arrivals)
+        st, _ = advance(st, jnp.float64(_BIG_TIME), funcs, services,
+                        arrivals)
         if auto_on:
             # drain tail: the fleet stays provisioned until the last
             # completion (advance stops accumulating when idle)
@@ -882,7 +912,7 @@ def _build_engine(policy: PolicySpec, cluster: ClusterCfg,
                 else jnp.float64(W)
             st = st._replace(tl=tln_engine.on_prov(
                 st.tl, t_last, (st.now - t_last) * n_prov * TL_CORES))
-        return st
+        return (st, iters) if count_drain else st
 
     if not stream:
         return run
@@ -930,7 +960,8 @@ def _build_engine(policy: PolicySpec, cluster: ClusterCfg,
         """One compiled scan over a chunk of arrivals.
 
         Returns ``(st, ys)`` where ``ys`` are the per-arrival
-        ``(rejected, cold, worker)`` outputs of the chunk.
+        ``(rejected, cold, worker)`` outputs of the chunk (and the drain
+        iterations with ``count_drain``).
         """
         xs = (gids, valid, arrivals, funcs, u_lb, services)
         return lax.scan(
@@ -940,7 +971,7 @@ def _build_engine(policy: PolicySpec, cluster: ClusterCfg,
     def run_drain(st):
         """End-of-horizon drain — the monolithic engine's scan tail."""
         t_last = st.now
-        st = advance(st, jnp.float64(_BIG_TIME), None, None, None)
+        st, _ = advance(st, jnp.float64(_BIG_TIME), None, None, None)
         if auto_on:
             fl = st.fleet
             st = st._replace(fleet=dict(fl, prov_time=(
@@ -1000,21 +1031,22 @@ def _cache_key(policy: PolicySpec, cluster: ClusterCfg,
                telemetry: TelemetryCfg | None = None,
                chunk: int | None = None,
                timeline: TimelineCfg | None = None,
-               mesh=None) -> tuple:
+               mesh=None, count_drain: bool = False) -> tuple:
     # telemetry-on engines trace a different program, so the cfg is part
     # of the key (None = the golden pre-telemetry program).  ``chunk``
     # marks a streaming chunk engine (the chunk size IS the key's shape
     # axis — n_arrivals then holds the chunk length, and one compiled
     # program serves any horizon); None = monolithic.  ``timeline``
     # likewise gates a different traced program (the flight-recorder
-    # plane), so its cfg joins the key as a trailing element, as does
-    # the device mesh of a rep-sharded streaming engine.
+    # plane), so its cfg joins the key as a trailing element, as do
+    # the device mesh of a rep-sharded streaming engine and the drain
+    # counter of a traced run.
     return (tuple(policy), tuple(cluster), int(n_arrivals),
             int(n_functions), batched, backend,
             None if telemetry is None else tuple(telemetry),
             None if chunk is None else int(chunk),
             None if timeline is None else tuple(timeline),
-            mesh)
+            mesh, bool(count_drain))
 
 
 def _cache_get_or_build(key: tuple, build):
@@ -1077,21 +1109,25 @@ def clear_engine_cache() -> None:
 def _get_engine(policy: PolicySpec, cluster: ClusterCfg,
                 n_arrivals: int, n_functions: int, batched: bool,
                 backend: str, telemetry: TelemetryCfg | None,
-                timeline: TimelineCfg | None = None):
+                timeline: TimelineCfg | None = None,
+                count_drain: bool = False):
     """Cached engine lookup; returns ``(engine, fresh)``.
 
     ``fresh`` marks a cache-miss build — the next dispatch through the
     callable pays XLA compilation, which :func:`simulate` /
     :func:`simulate_many` surface as an ``engine.first_run`` span
     (vs ``engine.run`` for steady-state cached dispatches).
+    ``count_drain`` builds the engine that also returns the drain
+    iterations (see :func:`_build_engine`).
     """
     cluster.validate()   # named errors instead of deep broadcast failures
     backend = _resolve_backend(policy, backend)
     key = _cache_key(policy, cluster, n_arrivals, n_functions, batched,
-                     backend, telemetry, timeline=timeline)
+                     backend, telemetry, timeline=timeline,
+                     count_drain=count_drain)
     raw = lambda: _build_engine(policy, cluster, n_arrivals, n_functions,
                                 backend, telemetry=telemetry,
-                                timeline=timeline)
+                                timeline=timeline, count_drain=count_drain)
     if batched:
         return _cache_get_or_build(key, lambda: jax.jit(jax.vmap(raw())))
     return _cache_get_or_build(key, lambda: jax.jit(raw()))
@@ -1101,7 +1137,7 @@ def _get_stream_engine(policy: PolicySpec, cluster: ClusterCfg,
                        chunk: int, n_functions: int, backend: str,
                        telemetry: TelemetryCfg | None,
                        timeline: TimelineCfg | None = None,
-                       mesh=None):
+                       mesh=None, count_drain: bool = False):
     """Cached streaming chunk-engine lookup.
 
     Returns ``((init, step_fn, drain_fn), fresh)``.  ``step_fn`` is the
@@ -1112,6 +1148,7 @@ def _get_stream_engine(policy: PolicySpec, cluster: ClusterCfg,
     instead of the horizon — growing ``N`` reuses one compiled program.
     With a 1-D ``"rep"`` ``mesh`` both programs run per device on its
     block of replications (:func:`repro.distribution.sim_shard.rep_local`).
+    ``count_drain`` adds the drain iterations to the chunk's outputs.
     """
     if chunk < 1:
         raise ValueError(f"chunk_size must be >= 1, got {chunk}")
@@ -1119,12 +1156,13 @@ def _get_stream_engine(policy: PolicySpec, cluster: ClusterCfg,
     backend = _resolve_backend(policy, backend)
     key = _cache_key(policy, cluster, int(chunk), n_functions, True,
                      backend, telemetry, chunk=int(chunk),
-                     timeline=timeline, mesh=mesh)
+                     timeline=timeline, mesh=mesh, count_drain=count_drain)
 
     def build():
         init, run_chunk, run_drain = _build_engine(
             policy, cluster, int(chunk), n_functions, backend,
-            telemetry=telemetry, timeline=timeline, stream=True)
+            telemetry=telemetry, timeline=timeline, stream=True,
+            count_drain=count_drain)
         # carry batched over reps; gids/valid unbatched so the padding
         # cond keeps a scalar predicate (a real branch, not a select)
         batched = (0, None, None, 0, 0, 0, 0, 0)
@@ -1203,6 +1241,39 @@ def _prov_core_s(st, cluster: ClusterCfg):
     return np.asarray(st.now) * cluster.n_workers * cluster.cores
 
 
+@jax.jit
+def _drain_totals(iters):
+    """``[R, steps]`` drain iterations reduced to their sum and the sum
+    over steps of the max over replications, which is what a vmapped
+    ``while_loop`` runs."""
+    return (iters.sum(dtype=jnp.int64),
+            iters.max(axis=0).sum(dtype=jnp.int64))
+
+
+def _count_drain(tracer, iters: list, n_reps: int, steps: int) -> None:
+    """Record a run's drain iterations (``[R, steps]`` device arrays) as
+    one ``drain.iterations`` counter sample: ``needed``, the sum over
+    steps of the mean over replications; ``executed``, the sum over
+    steps of the max; and the ``steps``.  Reducing them and bringing the
+    totals to the host is an ``engine.count`` span."""
+    with tracer.span("engine.count"):
+        totals = jax.device_get([_drain_totals(it) for it in iters])
+    tracer.count("drain.iterations",
+                 needed=sum(int(t) for t, _ in totals) / n_reps,
+                 executed=sum(int(e) for _, e in totals), steps=steps)
+
+
+def _record_scopes(tracer, label: str, fn, *args) -> None:
+    """Keep on the tracer, once per ``label``, the map from each HLO
+    instruction of the program the jitted ``fn`` runs on ``args`` to its
+    innermost :data:`STEP_SCOPES` scope.  Where ``fn`` already ran on
+    such arguments, lowering and compiling hit jit's caches; where it
+    has not, its first call reuses this compilation."""
+    if label not in tracer.scopes:
+        tracer.scopes[label] = op_scopes(
+            fn.lower(*args).compile().as_text(), STEP_SCOPES)
+
+
 def simulate(policy: PolicySpec, cluster: ClusterCfg, wl: Workload,
              *, backend: str = "auto",
              telemetry: TelemetryCfg | None = None,
@@ -1259,31 +1330,53 @@ def simulate_many(policy: PolicySpec, cluster: ClusterCfg,
     :class:`~repro.telemetry.TelemetryResult` keeps the leading ``R``
     axis; its percentile readers pool across it (matching
     ``summarize_batch``'s pooled statistics).
+
+    With the process-wide tracer on, the call records the spans
+    ``engine.put`` (inputs to the device), ``engine.run`` (or
+    ``engine.first_run`` after a build; waits for the device) and
+    ``engine.readout`` (outputs to the host); a ``drain.iterations``
+    counter and its ``engine.count`` span (see :func:`_count_drain`); and
+    the compiled program's
+    op-to-scope map in the tracer's ``scopes``.  The engine it runs then
+    counts drain iterations (a separate cache entry).
     """
     wb = workloads if isinstance(workloads, WorkloadBatch) \
         else stack_workloads(workloads)
-    run, fresh = _get_engine(policy, cluster, wb.n, wb.n_functions,
-                             True, backend, telemetry, timeline)
     tr = get_tracer()
+    run, fresh = _get_engine(policy, cluster, wb.n, wb.n_functions,
+                             True, backend, telemetry, timeline,
+                             count_drain=tr.enabled)
+    with tr.span("engine.put"):
+        args = (jnp.asarray(wb.arrival), jnp.asarray(wb.func),
+                jnp.asarray(wb.service), jnp.asarray(wb.u_lb),
+                jnp.asarray(wb.func_home))
     with tr.span("engine.first_run" if fresh else "engine.run",
                  policy=str(policy), backend=backend, n=wb.n,
                  reps=wb.n_reps):
-        st = run(jnp.asarray(wb.arrival), jnp.asarray(wb.func),
-                 jnp.asarray(wb.service), jnp.asarray(wb.u_lb),
-                 jnp.asarray(wb.func_home))
+        st = run(*args)
         if tr.enabled:
-            st = jax.block_until_ready(st)
-    return BatchSimOutput(
-        response=np.asarray(st.resp[:, :wb.n]),
-        cold=np.asarray(st.cold[:, :wb.n]),
-        rejected=np.asarray(st.rejected[:, :wb.n]),
-        worker=np.asarray(st.worker_of[:, :wb.n]),
-        server_time=np.asarray(st.server_time),
-        core_time=np.asarray(st.core_time),
-        end_time=np.asarray(st.now),
-        telemetry=None if telemetry is None else TelemetryResult.from_state(
-            jax.tree_util.tree_map(np.asarray, st.tel), cfg=telemetry),
-        prov_core_s=np.asarray(_prov_core_s(st, cluster), dtype=np.float64),
-        timeline=None if timeline is None else TimelineResult.from_state(
-            jax.tree_util.tree_map(np.asarray, st.tl), cfg=timeline),
-    )
+            st, iters = jax.block_until_ready(st)
+    if tr.enabled:
+        _record_scopes(tr, f"simulate_many {policy} {backend} n={wb.n} "
+                       f"f={wb.n_functions} reps={wb.n_reps}", run, *args)
+    with tr.span("engine.readout"):
+        out = BatchSimOutput(
+            response=np.asarray(st.resp[:, :wb.n]),
+            cold=np.asarray(st.cold[:, :wb.n]),
+            rejected=np.asarray(st.rejected[:, :wb.n]),
+            worker=np.asarray(st.worker_of[:, :wb.n]),
+            server_time=np.asarray(st.server_time),
+            core_time=np.asarray(st.core_time),
+            end_time=np.asarray(st.now),
+            telemetry=None if telemetry is None
+            else TelemetryResult.from_state(
+                jax.tree_util.tree_map(np.asarray, st.tel), cfg=telemetry),
+            prov_core_s=np.asarray(_prov_core_s(st, cluster),
+                                   dtype=np.float64),
+            timeline=None if timeline is None
+            else TimelineResult.from_state(
+                jax.tree_util.tree_map(np.asarray, st.tl), cfg=timeline),
+        )
+    if tr.enabled:
+        _count_drain(tr, [iters], wb.n_reps, wb.n)
+    return out

@@ -47,7 +47,8 @@ from repro.telemetry.state import (TelemetryCfg, TelemetryResult,
 from repro.telemetry.timeline import TimelineCfg, TimelineResult
 
 from .cluster import ClusterCfg
-from .simulator import SimState, _get_stream_engine, _prov_core_s
+from .simulator import (SimState, _count_drain, _get_stream_engine,
+                        _prov_core_s, _record_scopes)
 from .taxonomy import PolicySpec
 from .workload import Workload, WorkloadBatch, stack_workloads
 
@@ -130,6 +131,14 @@ def simulate_stream(policy: PolicySpec, cluster: ClusterCfg,
     segment (the per-segment parity hook).  The *next* chunk dispatch
     donates that carry's buffers — callbacks must ``np.asarray`` any
     leaf they keep.
+
+    With the process-wide tracer on, the call records the spans
+    ``stream.init`` (the initial carry), one ``stream.feed`` per chunk
+    (slicing, transfer and sharding of its inputs), ``stream.drain``
+    (the final drain, waiting for the device) inside ``engine.run``,
+    and ``engine.readout``; the chunk engine then counts drain
+    iterations, recorded (with an ``engine.count`` span) as in
+    :func:`repro.core.simulate_many`.
     """
     if isinstance(workloads, Workload):
         workloads = [workloads]
@@ -139,8 +148,10 @@ def simulate_stream(policy: PolicySpec, cluster: ClusterCfg,
         telemetry = TelemetryCfg()
     k = int(chunk_size)
     N, F, R = wb.n, wb.n_functions, wb.n_reps
+    tr = get_tracer()
     (init, step_fn, drain_fn), fresh = _get_stream_engine(
-        policy, cluster, k, F, backend, telemetry, timeline, mesh=mesh)
+        policy, cluster, k, F, backend, telemetry, timeline, mesh=mesh,
+        count_drain=tr.enabled)
     cutoff = warmup_cutoff(N, telemetry)
     # the runtime window width is horizon-dependent (auto = horizon/K),
     # so it is computed host-side per replication and written into the
@@ -182,53 +193,67 @@ def simulate_stream(policy: PolicySpec, cluster: ClusterCfg,
         shard = lambda tree: shard_reps(tree, mesh)
         homes = shard(homes)
 
-    st = init(R, cutoff, window_s)
-    if shard is not None:
-        st = shard(st)
+    with tr.span("stream.init"):
+        st = init(R, cutoff, window_s)
+        if shard is not None:
+            st = shard(st)
     outs: list[tuple] = []
-    tr = get_tracer()
+    iters: list = []
     with tr.span("engine.first_run" if fresh else "engine.run",
                  policy=str(policy), backend=backend, n=N, reps=R,
                  chunk=k, chunks=n_chunks):
         for c in range(n_chunks):
-            sl = slice(c * k, (c + 1) * k)
-            ins = (jnp.asarray(arr[:, sl]), jnp.asarray(fns[:, sl]),
-                   jnp.asarray(svcs[:, sl]), jnp.asarray(us[:, sl]))
-            if shard is not None:
-                ins = shard(ins)
-            st, ys = step_fn(st, jnp.asarray(gids[sl]),
-                             jnp.asarray(valid[sl]),
-                             ins[0], ins[1], ins[2], ins[3], homes)
+            with tr.span("stream.feed", chunk=c):
+                sl = slice(c * k, (c + 1) * k)
+                ins = (jnp.asarray(arr[:, sl]), jnp.asarray(fns[:, sl]),
+                       jnp.asarray(svcs[:, sl]), jnp.asarray(us[:, sl]))
+                if shard is not None:
+                    ins = shard(ins)
+                args = (st, jnp.asarray(gids[sl]), jnp.asarray(valid[sl]),
+                        *ins, homes)
+            if tr.enabled and c == 0:
+                # before the call, which donates the carry
+                _record_scopes(tr, f"simulate_stream {policy} {backend} "
+                               f"chunk={k} f={F} reps={R} mesh={mesh}",
+                               step_fn, *args)
+            st, ys = step_fn(*args)
             if collect_outputs:
-                outs.append(tuple(np.asarray(y) for y in ys))
+                outs.append(tuple(np.asarray(y) for y in ys[:3]))
+            if tr.enabled:
+                iters.append(ys[3])
             if chunk_callback is not None:
                 chunk_callback(c, st)
-        st = drain_fn(st)
-        st = jax.block_until_ready(st)
+        with tr.span("stream.drain"):
+            st = drain_fn(st)
+            st = jax.block_until_ready(st)
 
-    sc = jax.tree_util.tree_map(np.asarray, st.stream)
-    denom = np.maximum(sc["n_obs"], 1).astype(np.float64)
-    cold = rej = wkr = None
-    if collect_outputs:
-        rej = np.concatenate([o[0] for o in outs], axis=1)[:, :N]
-        cold = np.concatenate([o[1] for o in outs], axis=1)[:, :N]
-        wkr = np.concatenate([o[2] for o in outs], axis=1)[:, :N]
-    return StreamOutput(
-        telemetry=TelemetryResult.from_state(
-            jax.tree_util.tree_map(np.asarray, st.tel), cfg=telemetry),
-        n_done=sc["n_done"], n_observed=sc["n_obs"],
-        resp_mean=sc["resp_sum"] / denom,
-        slow_mean=sc["slow_sum"] / denom,
-        server_time=np.asarray(st.server_time),
-        core_time=np.asarray(st.core_time),
-        end_time=np.asarray(st.now),
-        prov_core_s=np.asarray(_prov_core_s(st, cluster),
-                               dtype=np.float64),
-        n_arrivals=N, chunk_size=k, n_chunks=n_chunks,
-        cold=cold, rejected=rej, worker=wkr,
-        final_state=st if keep_final_state else None,
-        timeline=None if timeline is None else TimelineResult.from_state(
-            jax.tree_util.tree_map(np.asarray, st.tl), cfg=timeline))
+    with tr.span("engine.readout"):
+        sc = jax.tree_util.tree_map(np.asarray, st.stream)
+        denom = np.maximum(sc["n_obs"], 1).astype(np.float64)
+        cold = rej = wkr = None
+        if collect_outputs:
+            rej = np.concatenate([o[0] for o in outs], axis=1)[:, :N]
+            cold = np.concatenate([o[1] for o in outs], axis=1)[:, :N]
+            wkr = np.concatenate([o[2] for o in outs], axis=1)[:, :N]
+        out = StreamOutput(
+            telemetry=TelemetryResult.from_state(
+                jax.tree_util.tree_map(np.asarray, st.tel), cfg=telemetry),
+            n_done=sc["n_done"], n_observed=sc["n_obs"],
+            resp_mean=sc["resp_sum"] / denom,
+            slow_mean=sc["slow_sum"] / denom,
+            server_time=np.asarray(st.server_time),
+            core_time=np.asarray(st.core_time),
+            end_time=np.asarray(st.now),
+            prov_core_s=np.asarray(_prov_core_s(st, cluster),
+                                   dtype=np.float64),
+            n_arrivals=N, chunk_size=k, n_chunks=n_chunks,
+            cold=cold, rejected=rej, worker=wkr,
+            final_state=st if keep_final_state else None,
+            timeline=None if timeline is None else TimelineResult.from_state(
+                jax.tree_util.tree_map(np.asarray, st.tl), cfg=timeline))
+    if tr.enabled:
+        _count_drain(tr, iters, R, N)
+    return out
 
 
 def final_states_equal(a: SimState, b: SimState

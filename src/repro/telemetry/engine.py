@@ -19,12 +19,17 @@ Parity contract with the numpy side:
   the same event order — equal to ~1e-9 relative (same tolerance class
   as the engines' ``server_time`` agreement).
 
+Each updater runs under ``jax.named_scope("tel")``, so a device
+profile can attribute the plane's operations (see
+:data:`repro.core.simulator.STEP_SCOPES`).
+
 This module is jax-only by design: it is imported from
 ``repro.core.simulator`` (a hot-path module), never from the numpy
 oracle, so ``repro.telemetry`` stays importable without jax.
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 from .sketch import N_BINS, hist_edges
@@ -57,6 +62,7 @@ def bin_index(x, edges) -> jnp.ndarray:
                     0, N_BINS - 1)
 
 
+@jax.named_scope("tel")
 def on_place(tel: dict, worker, is_cold, evicted) -> dict:
     """Record one placement (callers only place *accepted* arrivals)."""
     cold = is_cold.astype(jnp.int64)
@@ -69,6 +75,7 @@ def on_place(tel: dict, worker, is_cold, evicted) -> dict:
     }
 
 
+@jax.named_scope("tel")
 def on_advance(tel: dict, tau, active, depth, qlen) -> dict:
     """Accumulate pre-advance occupancy integrals over interval ``tau``."""
     return {
@@ -82,6 +89,7 @@ def on_advance(tel: dict, tau, active, depth, qlen) -> dict:
     }
 
 
+@jax.named_scope("tel")
 def on_complete(tel: dict, response, service, arr_idx, completed,
                 cutoff, edges) -> dict:
     """Scatter one (masked) completion into both histograms.
@@ -104,11 +112,13 @@ def on_complete(tel: dict, response, service, arr_idx, completed,
     }
 
 
+@jax.named_scope("tel")
 def on_evict(tel: dict, count) -> dict:
     """Add ``count`` lifecycle (idle-budget / keep-alive) evictions."""
     return {**tel, "n_evict": tel["n_evict"] + count.astype(jnp.int64)}
 
 
+@jax.named_scope("tel")
 def on_reject(tel: dict, rejected) -> dict:
     return {**tel,
             "n_reject": tel["n_reject"] + rejected.astype(jnp.int64)}

@@ -23,11 +23,16 @@ Parity contract with the numpy side:
   ``mode="drop"`` — the count keeps incrementing past the bound so
   truncation is observable, exactly like the numpy side.
 
+Each updater runs under ``jax.named_scope("tl")``, so a device
+profile can attribute the plane's operations (see
+:data:`repro.core.simulator.STEP_SCOPES`).
+
 jax-only by design (imported from ``repro.core.simulator``, never from
 the numpy oracle), like :mod:`repro.telemetry.engine`.
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 from .engine import bin_index
@@ -77,6 +82,7 @@ def _k(tl: dict, t):
     return window_index(t, tl["window_s"], tl["arrivals"].shape[0])
 
 
+@jax.named_scope("tl")
 def on_arrival(tl: dict, t, n_on) -> dict:
     """Count an arrival; last-write-wins the active-worker level."""
     k = _k(tl, t)
@@ -88,6 +94,7 @@ def on_arrival(tl: dict, t, n_on) -> dict:
     }
 
 
+@jax.named_scope("tl")
 def on_place(tl: dict, t, is_cold, evicted) -> dict:
     """Record one placement (callers only place *accepted* arrivals)."""
     k = _k(tl, t)
@@ -100,6 +107,7 @@ def on_place(tl: dict, t, is_cold, evicted) -> dict:
     }
 
 
+@jax.named_scope("tl")
 def on_advance(tl: dict, t, tau, active, qlen) -> dict:
     """Busy/queue-length integrals, credited to the interval start —
     the same left-Riemann convention as ``server_time``."""
@@ -113,6 +121,7 @@ def on_advance(tl: dict, t, tau, active, qlen) -> dict:
     }
 
 
+@jax.named_scope("tl")
 def on_complete(tl: dict, t, response, service, completed,
                 edges) -> dict:
     """Coarse sketch scatter at the (masked) completion time."""
@@ -132,23 +141,27 @@ def on_complete(tl: dict, t, response, service, completed,
     }
 
 
+@jax.named_scope("tl")
 def on_evict(tl: dict, t, count) -> dict:
     k = _k(tl, t)
     return {**tl, "n_evict": tl["n_evict"].at[k].add(
         count.astype(jnp.int64))}
 
 
+@jax.named_scope("tl")
 def on_reject(tl: dict, t, rejected) -> dict:
     k = _k(tl, t)
     return {**tl, "n_reject": tl["n_reject"].at[k].add(
         rejected.astype(jnp.int64))}
 
 
+@jax.named_scope("tl")
 def on_prov(tl: dict, t, core_s) -> dict:
     k = _k(tl, t)
     return {**tl, "prov_core": tl["prov_core"].at[k].add(core_s)}
 
 
+@jax.named_scope("tl")
 def on_event(tl: dict, record, t, kind: int, val, p99) -> dict:
     """Masked append to the bounded decision log.
 

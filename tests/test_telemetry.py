@@ -210,7 +210,7 @@ def test_tracer_spans_export_chrome_trace(tmp_path):
     with tr.span("outer", mode="test"):
         with tr.span("inner"):
             pass
-    tr.instant("mark")
+    tr.count("mark", value=3)
     tr.event_at("task", 1.5, 0.25, tid=2, cold=True)
     path = tmp_path / "trace.json"
     tr.export(str(path))
@@ -218,6 +218,8 @@ def test_tracer_spans_export_chrome_trace(tmp_path):
     evs = doc["traceEvents"]
     names = [e["name"] for e in evs]
     assert "outer" in names and "inner" in names and "task" in names
+    mark = next(e for e in evs if e["name"] == "mark")
+    assert mark["ph"] == "C" and mark["args"] == {"value": 3.0}
     complete = [e for e in evs if e["ph"] == "X"]
     assert all(e["dur"] >= 0 for e in complete)
     task = next(e for e in evs if e["name"] == "task")
@@ -233,6 +235,61 @@ def test_disabled_tracer_records_nothing():
     with tr.span("x"):
         pass
     assert tr.events == []
+
+
+def test_disabled_span_is_one_shared_null_context():
+    tr = Tracer(enabled=False)
+    assert tr.span("a", k=1) is tr.span("b")
+    tr.count("c", value=1)
+    assert tr.events == []
+
+
+def test_spans_record_parents_on_the_integer_clock():
+    import time
+    before = time.perf_counter_ns()
+    tr = Tracer(enabled=True)
+    after = time.perf_counter_ns()
+    assert isinstance(tr.t0_ns, int) and before <= tr.t0_ns <= after
+    with tr.span("outer"):
+        with tr.span("inner"):
+            tr.count("n", value=2)
+        with tr.span("second"):
+            pass
+    spans = {e["name"]: e for e in tr.events if e["ph"] == "X"}
+    assert spans["outer"]["parent"] is None
+    assert spans["inner"]["parent"] == spans["outer"]["id"]
+    assert spans["second"]["parent"] == spans["outer"]["id"]
+    count = next(e for e in tr.events if e["ph"] == "C")
+    assert count["parent"] == spans["inner"]["id"]
+    outer = spans["outer"]
+    end_us = (time.perf_counter_ns() - tr.t0_ns) / 1e3
+    assert 0 <= outer["ts"] <= spans["inner"]["ts"]
+    assert spans["second"]["ts"] + spans["second"]["dur"] \
+        <= outer["ts"] + outer["dur"] <= end_us
+
+
+def test_bridged_spans_open_profiler_annotations(monkeypatch):
+    import jax.profiler
+    opened = []
+
+    class Annotation:
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            opened.append(("enter", self.name))
+
+        def __exit__(self, *exc):
+            opened.append(("exit", self.name))
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Annotation)
+    tr = Tracer(enabled=True, jax_bridge=True)
+    monkeypatch.undo()          # the tracer keeps what it imported once
+    with tr.span("outer"):
+        with tr.span("inner"):
+            pass
+    assert opened == [("enter", "outer"), ("enter", "inner"),
+                      ("exit", "inner"), ("exit", "outer")]
 
 
 def test_wall_split_from_aggregate():

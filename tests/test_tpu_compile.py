@@ -116,3 +116,52 @@ def test_hermes_stream_chunk_engine_compiles_for_v5e(one_chip,
         _sds((R, k), f64, one_chip), _sds((R, F), i32, one_chip)
     ).compile().as_text()
     assert "tpu_custom_call" in hlo
+
+
+def test_counted_testbed_engine_compiles_for_v5e(one_chip, compiled_kernels):
+    """The sweep cell's engine as a traced run builds it: the drain's
+    iterations leave as a per-step output, and the Hermes launch sits in
+    the ``select`` scope of the compiled program's op-to-scope map."""
+    from repro.telemetry.spans import op_scopes
+    R, N, F = 32, 20_000, 40
+    engine, _ = compiled_kernels._get_engine(
+        HERMES, PAPER_TESTBED, N, F, True, "pallas", None, count_drain=True)
+    f64, i32 = jnp.float64, jnp.int32
+    shapes = (_sds((R, N), f64, one_chip), _sds((R, N), i32, one_chip),
+              _sds((R, N), f64, one_chip), _sds((R, N), f64, one_chip),
+              _sds((R, F), i32, one_chip))
+    _, iters = jax.eval_shape(engine, *shapes)
+    assert (iters.shape, iters.dtype) == ((R, N), jnp.int32)
+    hlo = engine.lower(*shapes).compile().as_text()
+    assert "tpu_custom_call" in hlo
+    scopes = op_scopes(hlo, compiled_kernels.STEP_SCOPES)
+    kernel = {s for op, s in scopes.items() if op.startswith("hermes_select")}
+    assert kernel == {"select"}
+    assert {"advance", "place"} <= set(scopes.values())
+
+
+def test_counted_stream_chunk_engine_compiles_for_v5e(one_chip,
+                                                      compiled_kernels):
+    """The stream cell's chunk engine as a traced run builds it (W=100 x
+    12 cores, E/LL/PS, telemetry on, chunk 4,096, R=8): the drain's
+    iterations are a fourth per-step output."""
+    from repro.core import E_LL_PS, ClusterCfg
+    from repro.telemetry import TelemetryCfg
+    from repro.telemetry.spans import op_scopes
+    cl = ClusterCfg(n_workers=100, cores=12, capacity_factor=8)
+    k, F, R = 4096, 40, 8
+    (init, step_fn, _), _ = compiled_kernels._get_stream_engine(
+        E_LL_PS, cl, k, F, "jax", TelemetryCfg(), count_drain=True)
+    carry = jax.tree_util.tree_map(
+        lambda a: _sds(a.shape, a.dtype, one_chip),
+        jax.eval_shape(lambda: init(R, 0)))
+    f64, i32 = jnp.float64, jnp.int32
+    shapes = (carry, _sds((k,), jnp.int64, one_chip),
+              _sds((k,), jnp.bool_, one_chip), _sds((R, k), f64, one_chip),
+              _sds((R, k), i32, one_chip), _sds((R, k), f64, one_chip),
+              _sds((R, k), f64, one_chip), _sds((R, F), i32, one_chip))
+    _, ys = jax.eval_shape(step_fn, *shapes)
+    assert len(ys) == 4 and (ys[3].shape, ys[3].dtype) == ((R, k), jnp.int32)
+    hlo = step_fn.lower(*shapes).compile().as_text()
+    scopes = op_scopes(hlo, compiled_kernels.STEP_SCOPES)
+    assert {"advance", "select", "place", "tel"} <= set(scopes.values())
