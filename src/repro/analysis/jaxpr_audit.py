@@ -23,7 +23,10 @@ failures:
   and the
   :func:`repro.core.simulator._cache_key` is probed — a field that
   changes the traced program but not the key would silently share a
-  compiled engine between different configs.
+  compiled engine between different configs,
+* ``JXP006`` — 64-bit integer ``div``/``rem`` inside a scan or
+  while body (the TPU emulates each as a long dependent chain, paid
+  on every iteration).
 
 :func:`audit_engines` also returns one stats row per engine (jaxpr eqn
 count, scan count, carry leaves/bytes) — the raw material for the
@@ -78,6 +81,17 @@ def iter_eqns(jaxpr) -> Iterable:
         yield eqn
         for sub in _sub_jaxprs(eqn.params):
             yield from iter_eqns(sub)
+
+
+def iter_loop_eqns(jaxpr, inside: bool = False) -> Iterable:
+    """Eqns of ``jaxpr`` that run inside a scan or while body, each once."""
+    jaxpr = getattr(jaxpr, "jaxpr", jaxpr)
+    for eqn in jaxpr.eqns:
+        if inside:
+            yield eqn
+        loop = inside or eqn.primitive.name in ("scan", "while")
+        for sub in _sub_jaxprs(eqn.params):
+            yield from iter_loop_eqns(sub, loop)
 
 
 def count_eqns(jaxpr) -> int:
@@ -168,6 +182,15 @@ def audit_jaxpr(closed, *, label: str = "<fn>",
                 if getattr(a, "weak_type", False):
                     find("JXP001", f"weak-typed while carry leaf "
                                    f"{_aval_str(a)}")
+
+    div64 = [eqn.primitive.name for eqn in iter_loop_eqns(closed)
+             if eqn.primitive.name in ("div", "rem")
+             and eqn.outvars[0].aval.dtype.kind in ("i", "u")
+             and eqn.outvars[0].aval.dtype.itemsize == 8]
+    if div64:
+        find("JXP006", f"{len(div64)} 64-bit integer div/rem inside "
+                       f"scan/while bodies "
+                       f"({', '.join(sorted(set(div64)))})")
 
     out_avals = closed.out_avals
     for i, a in enumerate(out_avals):
@@ -339,9 +362,10 @@ def trace_engine(policy, cluster, backend: str = "jax",
                         timeline=timeline)
     N, F = n_arrivals, n_functions
     f64 = jax.ShapeDtypeStruct((N,), jnp.float64)
-    i64 = jax.ShapeDtypeStruct((N,), jnp.int64)
-    homes = jax.ShapeDtypeStruct((F,), jnp.int64)
-    return jax.make_jaxpr(run)(f64, i64, f64, f64, homes)
+    # funcs and homes are int32, as every entry point passes them
+    funcs = jax.ShapeDtypeStruct((N,), jnp.int32)
+    homes = jax.ShapeDtypeStruct((F,), jnp.int32)
+    return jax.make_jaxpr(run)(f64, funcs, f64, f64, homes)
 
 
 def trace_stream_engine(policy, cluster, backend: str = "jax",
@@ -365,10 +389,11 @@ def trace_stream_engine(policy, cluster, backend: str = "jax",
         lambda x: jax.ShapeDtypeStruct(x.shape[1:], x.dtype), init(1, 0))
     k, F = int(chunk), n_functions
     f64 = jax.ShapeDtypeStruct((k,), jnp.float64)
-    i64 = jax.ShapeDtypeStruct((k,), jnp.int64)
+    gids = jax.ShapeDtypeStruct((k,), jnp.int64)
     valid = jax.ShapeDtypeStruct((k,), jnp.bool_)
-    homes = jax.ShapeDtypeStruct((F,), jnp.int64)
-    return jax.make_jaxpr(run_chunk)(st, i64, valid, f64, i64, f64,
+    funcs = jax.ShapeDtypeStruct((k,), jnp.int32)
+    homes = jax.ShapeDtypeStruct((F,), jnp.int32)
+    return jax.make_jaxpr(run_chunk)(st, gids, valid, f64, funcs, f64,
                                      f64, homes)
 
 

@@ -87,6 +87,10 @@ _RULES = (
          "add the field to repro.core.simulator._cache_key — two "
          "configs differing in it would silently share a compiled "
          "engine"),
+    Rule("JXP006", "64-bit integer div/rem inside a scan/while body",
+         "compute the index in int32 (e.g. lax.argmin(x, 0, jnp.int32)); "
+         "the TPU has no 64-bit integer unit and emulates each 64-bit "
+         "division as a long dependent chain, paid every iteration"),
     Rule("CON001", "balancer registry contract violation",
          "declared backends must be callable factories; stateful "
          "balancers (init_state set) must return (select, on_complete) "

@@ -281,6 +281,10 @@ def _build_engine(policy: PolicySpec, cluster: ClusterCfg,
     ``jax.named_scope`` names of :data:`STEP_SCOPES`.
     """
     W, C, S = cluster.n_workers, cluster.cores, cluster.slots
+    if W * S > np.iinfo(np.int32).max:
+        raise ValueError(
+            f"{W} workers x {S} slots = {W * S} slots do not fit the "
+            f"drain's int32 slot index")
     F = n_functions
     N = n_arrivals
     Q = N  # late-binding controller queue can hold every arrival
@@ -531,7 +535,10 @@ def _build_engine(policy: PolicySpec, cluster: ClusterCfg,
             # task left within EPS of done at the window edge completes
             # here (as both the old batch-done engine and the oracle do)
             # rather than stalling the loop
-            j = jnp.argmin(t_done.reshape(-1))
+            # int32 index (first minimum, as jnp.argmin): with x64 on,
+            # jnp.argmin yields int64, whose // and % the TPU emulates as
+            # a long dependent chain in every drain iteration
+            j = lax.argmin(t_done.reshape(-1), 0, jnp.int32)
             wj, sj = j // S, j % S
             tid = st.task_idx[wj, sj]
             completed = (tmin <= dt_left) | \

@@ -142,6 +142,40 @@ def test_jaxpr_audit_flags_weak_scan_carry():
     assert any(f.rule == "JXP001" for f in findings)
 
 
+def _split_in_loop(idx_dtype):
+    """A scan whose body splits an index into row and column."""
+    import jax
+
+    def toy(x):
+        def body(c, xi):
+            j = xi.astype(idx_dtype)
+            return c + j // 3 + j % 3, None
+        c, _ = jax.lax.scan(body, jax.numpy.zeros((), idx_dtype), x)
+        return c
+    return toy
+
+
+def test_jaxpr_audit_flags_int64_division_in_loop():
+    import repro.core.simulator  # noqa: F401 — enables x64 on import
+    import jax.numpy as jnp
+
+    _, findings = audit_fn(_split_in_loop(jnp.int64),
+                           _sds((4,), jnp.int32))
+    assert [f.rule for f in findings] == ["JXP006"], findings
+
+
+def test_jaxpr_audit_int32_or_unlooped_division_is_clean():
+    import repro.core.simulator  # noqa: F401 — enables x64 on import
+    import jax.numpy as jnp
+
+    _, findings = audit_fn(_split_in_loop(jnp.int32),
+                           _sds((4,), jnp.int32))
+    assert findings == []
+    _, findings = audit_fn(lambda x: x.astype(jnp.int64) // 3,
+                           _sds((4,), jnp.int32))
+    assert findings == []
+
+
 # --------------------------------------------------------------------------
 # jaxpr audit: the real engines
 # --------------------------------------------------------------------------
@@ -151,6 +185,12 @@ def test_audit_engines_smoke():
     assert [s.label for s in stats] == ["E/LL/PS|jax", "E/LL/PS|pallas"]
     assert findings == [], "\n".join(f.format() for f in findings)
     assert all(s.eqns > 0 and s.scans >= 1 for s in stats)
+
+
+def test_audit_all_engines_clean():
+    stats, findings = audit_engines()
+    assert len(stats) == len(BASELINES)
+    assert findings == [], "\n".join(f.format() for f in findings)
 
 
 def test_engine_specs_cover_every_balancer_and_backend():

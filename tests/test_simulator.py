@@ -108,3 +108,11 @@ def test_eviction_agreement_under_full_warm_pools(policy, seed):
         np.testing.assert_allclose(
             np.nan_to_num(out.response, nan=-1.0),
             np.nan_to_num(ref.response, nan=-1.0), atol=1e-6)
+
+
+def test_slot_index_beyond_int32_is_refused():
+    """The drain indexes the cluster's slots in int32."""
+    from repro.core.simulator import _build_engine
+    cl = ClusterCfg(n_workers=2 ** 20, cores=2 ** 9, capacity_factor=8)
+    with pytest.raises(ValueError, match="int32 slot index"):
+        _build_engine(HERMES, cl, n_arrivals=4, n_functions=2)

@@ -10,6 +10,8 @@ The topology is described inside a module fixture (only one process may
 load the TPU library, so never at import), and the persistent compile
 cache is off meanwhile: a described-chip entry cannot be read back.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -58,6 +60,40 @@ def _sds(shape, dtype, sharding):
 
 def _compile_text(fn, *shapes) -> str:
     return jax.jit(fn).lower(*shapes).compile().as_text()
+
+
+def _computations(hlo: str) -> dict[str, list[str]]:
+    """Compiled HLO text -> {computation name: its instruction lines}."""
+    comps, name = {}, None
+    for line in hlo.splitlines():
+        head = re.match(r"(?:ENTRY )?%?([\w.\-]+) .*\{$", line)
+        if head:
+            name = head.group(1)
+            comps[name] = []
+        elif line.startswith("}"):
+            name = None
+        elif name is not None and line.strip():
+            comps[name].append(line.strip())
+    return comps
+
+
+#: Bookkeeping that moves no data on the chip, left out of a body's count.
+_BOOKKEEPING = {"parameter", "get-tuple-element", "tuple", "constant"}
+
+
+def _drain_body(hlo: str) -> list[str]:
+    """Top-level instructions of the drain loop's body inside the scan step
+    (the ``while`` whose op_name ends in ``advance/while``), bookkeeping
+    left out."""
+    comps = _computations(hlo)
+    bodies = [re.search(r"body=%?([\w.\-]+)", line).group(1)
+              for lines in comps.values() for line in lines
+              if " while(" in line
+              and re.search(r'op_name="[^"]*advance/while"', line)]
+    assert len(bodies) == 1, bodies
+    return [line for line in comps[bodies[0]]
+            if re.search(r" = .*? ([a-z][\w\-]*)\(", line).group(1)
+            not in _BOOKKEEPING]
 
 
 @pytest.mark.parametrize("W,N", [(8, 1), (8, 256), (1000, 1)])
@@ -165,3 +201,24 @@ def test_counted_stream_chunk_engine_compiles_for_v5e(one_chip,
     hlo = step_fn.lower(*shapes).compile().as_text()
     scopes = op_scopes(hlo, compiled_kernels.STEP_SCOPES)
     assert {"advance", "select", "place", "tel"} <= set(scopes.values())
+
+
+def test_drain_loop_has_no_emulated_64bit_division(one_chip,
+                                                   compiled_kernels):
+    """The drain's argmin slot index is split into worker and slot in
+    int32: the chip has no 64-bit integer unit, so an int64 ``// S`` and
+    ``% S`` compile to a long dependent chain in every drain iteration."""
+    from repro.core import E_LL_PS
+    R, N, F = 4, 64, 8
+    engine = compiled_kernels.build_batch_simulator(
+        E_LL_PS, PAPER_TESTBED, n_arrivals=N, n_functions=F, backend="jax")
+    f64, i32 = jnp.float64, jnp.int32
+    hlo = engine.lower(
+        _sds((R, N), f64, one_chip), _sds((R, N), i32, one_chip),
+        _sds((R, N), f64, one_chip), _sds((R, N), f64, one_chip),
+        _sds((R, F), i32, one_chip)).compile().as_text()
+    body = _drain_body(hlo)
+    assert not [line for line in body if "floor_divide" in line]
+    # 49 with the int32 index; the int64 one compiled to 77, 27 of
+    # them the division
+    assert len(body) <= 49 + 4, len(body)
