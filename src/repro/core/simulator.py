@@ -1248,26 +1248,35 @@ def _prov_core_s(st, cluster: ClusterCfg):
     return np.asarray(st.now) * cluster.n_workers * cluster.cores
 
 
-@jax.jit
-def _drain_totals(iters):
-    """``[R, steps]`` drain iterations reduced to their sum and the sum
-    over steps of the max over replications, which is what a vmapped
-    ``while_loop`` runs."""
+@partial(jax.jit, static_argnums=1)
+def _drain_totals(iters, blocks: int):
+    """``[R, steps]`` drain iterations reduced to their sum and, per
+    block of ``R / blocks`` consecutive replications, the sum over steps
+    of the max over the block, which is what a vmapped ``while_loop``
+    over that block runs."""
+    per_block = iters.reshape(blocks, -1, iters.shape[1]).max(axis=1)
     return (iters.sum(dtype=jnp.int64),
-            iters.max(axis=0).sum(dtype=jnp.int64))
+            per_block.sum(axis=1, dtype=jnp.int64))
 
 
-def _count_drain(tracer, iters: list, n_reps: int, steps: int) -> None:
+def _count_drain(tracer, iters: list, n_reps: int, steps: int,
+                 blocks: int = 1) -> None:
     """Record a run's drain iterations (``[R, steps]`` device arrays) as
     one ``drain.iterations`` counter sample: ``needed``, the sum over
-    steps of the mean over replications; ``executed``, the sum over
-    steps of the max; and the ``steps``.  Reducing them and bringing the
-    totals to the host is an ``engine.count`` span."""
+    steps of the mean over replications; ``executed``, what each device's
+    loop ran, the mean over the ``blocks`` devices of a ``"rep"`` mesh of
+    the sum over steps of the max over the device's block;
+    ``executed_max``, the largest device's such sum; and the ``steps``.
+    With one block (no mesh) both are the sum over steps of the max over
+    all replications.  Reducing them and bringing the totals to the host
+    is an ``engine.count`` span."""
     with tracer.span("engine.count"):
-        totals = jax.device_get([_drain_totals(it) for it in iters])
+        totals = jax.device_get([_drain_totals(it, blocks) for it in iters])
+    ran = sum(np.asarray(b, np.int64) for _, b in totals)
     tracer.count("drain.iterations",
                  needed=sum(int(t) for t, _ in totals) / n_reps,
-                 executed=sum(int(e) for _, e in totals), steps=steps)
+                 executed=int(ran.sum()) / blocks,
+                 executed_max=int(ran.max()), steps=steps)
 
 
 def _record_scopes(tracer, label: str, fn, *args) -> None:

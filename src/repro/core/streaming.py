@@ -138,7 +138,13 @@ def simulate_stream(policy: PolicySpec, cluster: ClusterCfg,
     (the final drain, waiting for the device) inside ``engine.run``,
     and ``engine.readout``; the chunk engine then counts drain
     iterations, recorded (with an ``engine.count`` span) as in
-    :func:`repro.core.simulate_many`.
+    :func:`repro.core.simulate_many`.  With a ``mesh`` each placement on
+    it (``shard_reps``) is a ``stream.shard`` span: the function homes,
+    the carry inside ``stream.init`` and each chunk's inputs inside its
+    ``stream.feed``; and the counter's ``executed`` is per device, each
+    device's loop running to the max of its own block of replications
+    (``executed_max`` the largest device's), see
+    :func:`repro.core.simulator._count_drain`.
     """
     if isinstance(workloads, Workload):
         workloads = [workloads]
@@ -188,9 +194,14 @@ def simulate_stream(policy: PolicySpec, cluster: ClusterCfg,
     homes = jnp.asarray(wb.func_home)
 
     shard = None
+    blocks = 1
     if mesh is not None:
-        from repro.distribution.sim_shard import shard_reps
-        shard = lambda tree: shard_reps(tree, mesh)
+        from repro.distribution.sim_shard import REP_AXIS, shard_reps
+        blocks = mesh.shape[REP_AXIS]
+
+        def shard(tree):
+            with tr.span("stream.shard"):
+                return shard_reps(tree, mesh)
         homes = shard(homes)
 
     with tr.span("stream.init"):
@@ -252,7 +263,7 @@ def simulate_stream(policy: PolicySpec, cluster: ClusterCfg,
             timeline=None if timeline is None else TimelineResult.from_state(
                 jax.tree_util.tree_map(np.asarray, st.tl), cfg=timeline))
     if tr.enabled:
-        _count_drain(tr, iters, R, N)
+        _count_drain(tr, iters, R, N, blocks)
     return out
 
 

@@ -6,6 +6,8 @@ completion drain's loop iterations per scan step, and keep each compiled
 scan program's map from HLO instruction to named scope.  With it off
 they record nothing and run the uncounted program.
 """
+import json
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -205,3 +207,95 @@ def test_op_scopes_of_a_compiled_function():
              for line in text.splitlines() if " while(" in line}
     assert loops and not loops & set(scopes)
     assert op_scopes(text, ()) == dict.fromkeys(scopes, "")
+
+
+#: the stream traced without a mesh and on a 4-device ``"rep"`` mesh (W=8,
+#: R=8 at mixed loads, so the devices' blocks drain unevenly, 512
+#: arrivals in 4 chunks): each run's counter sample and span tree, and
+#: the per-replication iterations of the run without a mesh
+MESH_RUNS = """
+import json
+import numpy as np
+from repro.core import E_LL_PS, ClusterCfg, stack_workloads, synth_workload
+from repro.core import streaming
+from repro.launch.mesh import make_rep_mesh
+from repro.telemetry import TelemetryCfg
+from repro.telemetry.spans import Tracer, set_tracer
+
+cl = ClusterCfg(n_workers=8, cores=3, capacity_factor=2)
+wb = stack_workloads([synth_workload(cl, load, 512, n_functions=5,
+                                     hot_fraction=0.8, seed=seed)
+                      for seed, load in enumerate((0.4, 0.9, 1.3, 0.7) * 2)])
+kept = []
+count_drain = streaming._count_drain
+def keep(tr, iters, *args):
+    kept.append(np.concatenate([np.asarray(i) for i in iters], axis=1))
+    count_drain(tr, iters, *args)
+streaming._count_drain = keep
+out = {}
+for name, mesh in (("one", None), ("mesh", make_rep_mesh(4))):
+    def run():
+        streaming.simulate_stream(E_LL_PS, cl, wb, chunk_size=128,
+                                  backend="jax", telemetry=TelemetryCfg(),
+                                  mesh=mesh)
+    set_tracer(Tracer(enabled=True))
+    run()                                   # builds and compiles
+    tr = Tracer(enabled=True)
+    set_tracer(tr)
+    run()
+    by_id = {e["id"]: e["name"] for e in tr.events if e["ph"] == "X"}
+    out[name] = {
+        "counts": [e["args"] for e in tr.events
+                   if e["ph"] == "C" and e["name"] == "drain.iterations"],
+        "spans": [[e["name"], by_id.get(e["parent"])] for e in tr.events
+                  if e["ph"] == "X"]}
+out["iters"] = kept[1].tolist()          # the timed run without a mesh
+out["same"] = bool((kept[1] == kept[3]).all())
+print("RESULT " + json.dumps(out))
+"""
+
+
+@pytest.fixture(scope="module")
+def mesh_runs(devices_script):
+    out = devices_script(MESH_RUNS, n_devices=4)
+    line = [x for x in out.splitlines() if x.startswith("RESULT ")]
+    res = json.loads(line[-1][len("RESULT "):])
+    res["iters"] = np.asarray(res["iters"])
+    return res
+
+
+def test_mesh_drain_counter_is_per_device(mesh_runs):
+    """On 4 devices each one's loop runs to the max of its own block of
+    R/4 replications: ``executed`` is the mean over the blocks of each
+    block's per-step max summed, ``executed_max`` the largest block's."""
+    iters = mesh_runs["iters"]
+    R, steps = iters.shape
+    assert (R, steps) == (8, 512) and mesh_runs["same"]
+    per_block = iters.reshape(4, R // 4, steps).max(axis=1).sum(axis=1)
+    (count,) = mesh_runs["mesh"]["counts"]
+    assert count["steps"] == steps
+    assert count["needed"] == pytest.approx(iters.mean(axis=0).sum())
+    assert count["executed"] == per_block.mean()
+    assert count["executed_max"] == per_block.max()
+    # the blocks drain unevenly, and less than one loop over all of R
+    assert count["executed"] < count["executed_max"]
+    assert count["executed_max"] < iters.max(axis=0).sum()
+
+
+def test_one_device_drain_counter_is_unchanged(mesh_runs):
+    iters = mesh_runs["iters"]
+    (count,) = mesh_runs["one"]["counts"]
+    assert count["needed"] == pytest.approx(iters.mean(axis=0).sum())
+    assert count["executed"] == iters.max(axis=0).sum()
+    assert count["executed_max"] == count["executed"]
+
+
+def test_shard_spans_only_under_a_mesh(mesh_runs):
+    """Each placement on the mesh is a ``stream.shard`` span: the homes,
+    the carry inside ``stream.init`` and each of the 4 chunks' inputs
+    inside its ``stream.feed``; without a mesh there is none."""
+    shard = [p for n, p in mesh_runs["mesh"]["spans"] if n == "stream.shard"]
+    assert sorted(shard, key=str) == sorted(
+        [None, "stream.init"] + ["stream.feed"] * 4, key=str)
+    names = [n for n, _ in mesh_runs["one"]["spans"]]
+    assert "stream.shard" not in names and "stream.feed" in names
