@@ -28,61 +28,61 @@ from .rules import RULES
 #: AUDIT_F=3 functions, AUDIT_W=3 workers — counts are shape-dependent,
 #: keep in sync with :mod:`repro.analysis.jaxpr_audit`).
 BASELINES: dict[str, int] = {
-    "E/LOC/PS|jax": 578,
-    "E/LOC/PS|pallas": 578,
-    "E/R/PS|jax": 565,
-    "E/R/PS|pallas": 565,
-    "E/LL/PS|jax": 552,
-    "E/LL/PS|pallas": 552,
-    "E/H/PS|jax": 574,
-    "E/H/PS|pallas": 608,
-    "E/JSQ2/PS|jax": 580,
-    "E/JSQ2/PS|pallas": 580,
-    "E/RR/PS|jax": 587,
-    "E/RR/PS|pallas": 587,
-    "E/HIKU/PS|jax": 726,
-    "E/HIKU/PS|pallas": 726,
-    "E/DD/PS|jax": 644,
-    "E/DD/PS|pallas": 644,
-    "E/SWARM/PS|jax": 685,
-    "E/SWARM/PS|pallas": 685,
-    "E/LL/PS|jax|ka=NONE": 720,
-    "E/LL/PS|jax|ka=FIXED_TTL": 720,
-    "E/LL/PS|jax|ka=HYBRID_HIST": 818,
-    "L/LL/FCFS|jax": 1256,
+    "E/LOC/PS|jax": 575,
+    "E/LOC/PS|pallas": 575,
+    "E/R/PS|jax": 562,
+    "E/R/PS|pallas": 562,
+    "E/LL/PS|jax": 549,
+    "E/LL/PS|pallas": 549,
+    "E/H/PS|jax": 571,
+    "E/H/PS|pallas": 605,
+    "E/JSQ2/PS|jax": 577,
+    "E/JSQ2/PS|pallas": 577,
+    "E/RR/PS|jax": 584,
+    "E/RR/PS|pallas": 584,
+    "E/HIKU/PS|jax": 723,
+    "E/HIKU/PS|pallas": 723,
+    "E/DD/PS|jax": 641,
+    "E/DD/PS|pallas": 641,
+    "E/SWARM/PS|jax": 682,
+    "E/SWARM/PS|pallas": 682,
+    "E/LL/PS|jax|ka=NONE": 724,
+    "E/LL/PS|jax|ka=FIXED_TTL": 724,
+    "E/LL/PS|jax|ka=HYBRID_HIST": 822,
+    "L/LL/FCFS|jax": 1231,
     # telemetry-on lanes (streaming histogram/counter carry in the
     # scan); the telemetry-off baselines above are unchanged — the
     # disabled path traces the identical pre-telemetry program
-    "E/LL/PS|jax|tel": 762,
-    "E/H/PS|jax|tel": 784,
-    "E/HIKU/PS|jax|tel": 936,
-    "E/H/PS|pallas|tel": 818,
-    "E/LL/PS|jax|ka=FIXED_TTL|tel": 930,
-    "L/LL/FCFS|jax|tel": 1516,
+    "E/LL/PS|jax|tel": 759,
+    "E/H/PS|jax|tel": 781,
+    "E/HIKU/PS|jax|tel": 933,
+    "E/H/PS|pallas|tel": 815,
+    "E/LL/PS|jax|ka=FIXED_TTL|tel": 934,
+    "L/LL/FCFS|jax|tel": 1491,
     # heterogeneous-fleet lanes: the speed-vector divide costs ~4 eqns
     # on a speed-blind engine; SWARM's learned-state carry and the
     # TARGET_P99 autoscaler+telemetry lane are budgeted on top
-    "E/LL/PS|jax|fleet": 556,
-    "E/SWARM/PS|jax|fleet": 701,
-    "E/LL/PS|jax|fleet|auto|tel": 862,
+    "E/LL/PS|jax|fleet": 553,
+    "E/SWARM/PS|jax|fleet": 698,
+    "E/LL/PS|jax|fleet|auto|tel": 859,
     # streaming chunk-engine lanes: one segment's scan traced on the
     # engine's own init carry (slot mirrors + exact counters, no (N,)
     # output planes — hence smaller than the monolithic twins)
-    "E/LL/PS|jax|chunk": 365,
-    "E/LL/PS|jax|tel|chunk": 476,
-    "E/LL/PS|jax|ka=HYBRID_HIST|tel|chunk": 653,
-    "E/LL/PS|jax|fleet|auto|tel|chunk": 569,
+    "E/LL/PS|jax|chunk": 362,
+    "E/LL/PS|jax|tel|chunk": 473,
+    "E/LL/PS|jax|ka=HYBRID_HIST|tel|chunk": 657,
+    "E/LL/PS|jax|fleet|auto|tel|chunk": 566,
     # windowed-timeline lanes: the flight-recorder plane scatters into
     # K-window counters/sketches on every arrival and completion, so
     # it costs more than the telemetry sketch alone; timeline-off
     # baselines above are unchanged (the disabled path traces the
     # identical pre-timeline program — locked by
     # test_timeline_off_is_bit_identical)
-    "E/LL/PS|jax|tl": 1039,
-    "E/LL/PS|jax|tel|tl": 1249,
-    "E/H/PS|jax|tel|tl": 1305,
-    "E/LL/PS|jax|fleet|auto|tel|tl": 1424,
-    "E/LL/PS|jax|tel|tl|chunk": 756,
+    "E/LL/PS|jax|tl": 1036,
+    "E/LL/PS|jax|tel|tl": 1246,
+    "E/H/PS|jax|tel|tl": 1302,
+    "E/LL/PS|jax|fleet|auto|tel|tl": 1421,
+    "E/LL/PS|jax|tel|tl|chunk": 753,
 }
 
 #: Headroom multiplier over the measured baseline.

@@ -226,6 +226,31 @@ class BatchSimOutput:
             else self.timeline[sl])
 
 
+def _row(plane: jax.Array, w) -> jax.Array:
+    """Row ``w`` of a ``[W, n]`` plane, read by selecting it over the
+    worker axis and reducing that axis, bit for bit.
+
+    Under ``vmap`` with a worker per replication, an indexed row compiles
+    on the TPU to a serial loop of one trip per replication; this is one
+    elementwise pass and a reduction.  Integers reduce by a sum of the
+    selected row and zeros.  Floats reduce by a select per pair of rows,
+    so no value passes through arithmetic, which could flush a subnormal
+    or turn ``-0.0`` into ``0.0`` (the chip cannot bitcast float64).
+    """
+    hit = jnp.broadcast_to(
+        (jnp.arange(plane.shape[0], dtype=jnp.int32) == w)[:, None],
+        plane.shape)
+    if jnp.issubdtype(plane.dtype, jnp.integer):
+        return jnp.where(hit, plane, 0).sum(axis=0, dtype=plane.dtype)
+
+    def first_hit(a, b):
+        return jnp.where(a[1], a[0], b[0]), a[1] | b[1]
+
+    row, _ = lax.reduce((plane, hit), (jnp.zeros((), plane.dtype), False),
+                        first_hit, (0,))
+    return row
+
+
 def _build_engine(policy: PolicySpec, cluster: ClusterCfg,
                   n_arrivals: int, n_functions: int,
                   backend: str = "jax",
@@ -390,15 +415,16 @@ def _build_engine(policy: PolicySpec, cluster: ClusterCfg,
                 # LRU materialized pool — oldest idle-since, first index on
                 # ties, the tie-breaking contract shared with the oracle
                 lu = life["idle_since"]
+                lu_w = _row(lu[:, :F], w)
                 pre, keep = life["pre"], life["keep"]
-                ages_w = st.now - lu[w, :F]
+                ages_w = st.now - lu_w
                 mat_w = (ages_w >= pre) & (ages_w <= pre + keep)
-                eff = jnp.where(mat_w, st.warm[w, :F], 0)
+                eff = jnp.where(mat_w, _row(st.warm[:, :F], w), 0)
                 warm_cnt = eff[f]
                 is_cold = warm_cnt == 0
                 idle = eff.sum()
                 need_evict = is_cold & (active_w + idle >= S)
-                victim = jnp.argmin(jnp.where(eff > 0, lu[w, :F], jnp.inf))
+                victim = jnp.argmin(jnp.where(eff > 0, lu_w, jnp.inf))
                 pen_f = penalty if life_costs is None else life_costs[f]
                 if life_observe is not None:
                     # observe the placed pool's idle age AFTER the
@@ -413,11 +439,12 @@ def _build_engine(policy: PolicySpec, cluster: ClusterCfg,
                     pre2, keep2 = life_windows(ka)
                     life = dict(life, ka=ka, pre=pre2, keep=keep2)
         else:
-            warm_cnt = st.warm[w, f]
+            warm_w = _row(st.warm[:, :F], w)
+            warm_cnt = warm_w[f]
             is_cold = warm_cnt == 0
-            idle = st.warm[w, :F].sum()
+            idle = warm_w.sum()
             need_evict = is_cold & (active_w + idle >= S)
-            victim = jnp.argmax(st.warm[w, :F])
+            victim = jnp.argmax(warm_w)
             pen_f = penalty
         warm = st.warm.at[w, f].add(jnp.where(is_cold, 0, -1))
         warm = warm.at[w, victim].add(jnp.where(need_evict, -1, 0))

@@ -116,3 +116,41 @@ def test_slot_index_beyond_int32_is_refused():
     cl = ClusterCfg(n_workers=2 ** 20, cores=2 ** 9, capacity_factor=8)
     with pytest.raises(ValueError, match="int32 slot index"):
         _build_engine(HERMES, cl, n_arrivals=4, n_functions=2)
+
+
+def _tied_warm():
+    warm = np.zeros((3, 4, 6), np.int32)
+    warm[0, 2] = [1, 3, 0, 3, 2, 3]     # tied maxima (the victim: first)
+    warm[1, 0] = [0, 0, 5, 5, 0, 1]
+    warm[2, 3] = [7, 7, 7, 7, 7, 7]
+    warm[:, 1] = 9                      # a larger row nobody reads
+    return warm, np.array([2, 0, 3])
+
+
+def _zero_row():
+    warm = np.arange(3 * 4 * 6, dtype=np.int32).reshape(3, 4, 6)
+    warm[np.arange(3), [1, 3, 0]] = 0
+    return warm, np.array([1, 3, 0])
+
+
+def _idle_since():
+    lu = np.random.default_rng(0).normal(size=(3, 4, 6)) * 1e3
+    lu[0, 3] = [-1.0, -0.0, 0.0, -1e-300, 5.0, -0.0]   # virgin pools < 0
+    lu[1, 1] = -0.0
+    lu[2, 0] = [np.inf, -np.inf, -2.5, -0.0, 1e300, -5e-324]
+    return lu, np.array([3, 1, 0])
+
+
+@pytest.mark.parametrize("case", [_tied_warm, _zero_row, _idle_since],
+                         ids=["tied-maxima", "zero-row", "idle-since-f64"])
+def test_row_read_matches_indexing_per_replication(case):
+    """``place`` reads the chosen worker's row by a select and reduction
+    over the worker axis; under ``vmap``, with another worker per
+    replication, it gives the indexed row back bit for bit."""
+    import jax
+    from repro.core.simulator import _row
+    plane, ws = case()
+    got = np.asarray(jax.vmap(_row)(plane, ws))
+    want = plane[np.arange(len(ws)), ws]
+    assert got.dtype == want.dtype
+    np.testing.assert_array_equal(got.view(np.uint8), want.view(np.uint8))

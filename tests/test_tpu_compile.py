@@ -154,18 +154,47 @@ def test_hermes_stream_chunk_engine_compiles_for_v5e(one_chip,
     assert "tpu_custom_call" in hlo
 
 
+def _counted_sweep(sim, sharding):
+    """The sweep cell's engine as a traced run builds it (the paper
+    testbed, E/H/PS on the kernel, R=32 x 20,000 arrivals, F=40), and its
+    argument shapes."""
+    R, N, F = 32, 20_000, 40
+    engine, _ = sim._get_engine(
+        HERMES, PAPER_TESTBED, N, F, True, "pallas", None, count_drain=True)
+    f64, i32 = jnp.float64, jnp.int32
+    return engine, (_sds((R, N), f64, sharding), _sds((R, N), i32, sharding),
+                    _sds((R, N), f64, sharding), _sds((R, N), f64, sharding),
+                    _sds((R, F), i32, sharding))
+
+
+def _counted_stream(sim, sharding):
+    """The stream cell's chunk engine as a traced run builds it (W=100 x
+    12 cores, E/LL/PS, telemetry on, chunk 4,096, R=8, F=40), and its
+    argument shapes."""
+    from repro.core import E_LL_PS, ClusterCfg
+    from repro.telemetry import TelemetryCfg
+    cl = ClusterCfg(n_workers=100, cores=12, capacity_factor=8)
+    k, F, R = 4096, 40, 8
+    (init, step_fn, _), _ = sim._get_stream_engine(
+        E_LL_PS, cl, k, F, "jax", TelemetryCfg(), count_drain=True)
+    carry = jax.tree_util.tree_map(
+        lambda a: _sds(a.shape, a.dtype, sharding),
+        jax.eval_shape(lambda: init(R, 0)))
+    f64, i32 = jnp.float64, jnp.int32
+    return step_fn, (carry, _sds((k,), jnp.int64, sharding),
+                     _sds((k,), jnp.bool_, sharding),
+                     _sds((R, k), f64, sharding), _sds((R, k), i32, sharding),
+                     _sds((R, k), f64, sharding), _sds((R, k), f64, sharding),
+                     _sds((R, F), i32, sharding))
+
+
 def test_counted_testbed_engine_compiles_for_v5e(one_chip, compiled_kernels):
     """The sweep cell's engine as a traced run builds it: the drain's
     iterations leave as a per-step output, and the Hermes launch sits in
     the ``select`` scope of the compiled program's op-to-scope map."""
     from repro.telemetry.spans import op_scopes
-    R, N, F = 32, 20_000, 40
-    engine, _ = compiled_kernels._get_engine(
-        HERMES, PAPER_TESTBED, N, F, True, "pallas", None, count_drain=True)
-    f64, i32 = jnp.float64, jnp.int32
-    shapes = (_sds((R, N), f64, one_chip), _sds((R, N), i32, one_chip),
-              _sds((R, N), f64, one_chip), _sds((R, N), f64, one_chip),
-              _sds((R, F), i32, one_chip))
+    R, N = 32, 20_000
+    engine, shapes = _counted_sweep(compiled_kernels, one_chip)
     _, iters = jax.eval_shape(engine, *shapes)
     assert (iters.shape, iters.dtype) == ((R, N), jnp.int32)
     hlo = engine.lower(*shapes).compile().as_text()
@@ -181,21 +210,9 @@ def test_counted_stream_chunk_engine_compiles_for_v5e(one_chip,
     """The stream cell's chunk engine as a traced run builds it (W=100 x
     12 cores, E/LL/PS, telemetry on, chunk 4,096, R=8): the drain's
     iterations are a fourth per-step output."""
-    from repro.core import E_LL_PS, ClusterCfg
-    from repro.telemetry import TelemetryCfg
     from repro.telemetry.spans import op_scopes
-    cl = ClusterCfg(n_workers=100, cores=12, capacity_factor=8)
-    k, F, R = 4096, 40, 8
-    (init, step_fn, _), _ = compiled_kernels._get_stream_engine(
-        E_LL_PS, cl, k, F, "jax", TelemetryCfg(), count_drain=True)
-    carry = jax.tree_util.tree_map(
-        lambda a: _sds(a.shape, a.dtype, one_chip),
-        jax.eval_shape(lambda: init(R, 0)))
-    f64, i32 = jnp.float64, jnp.int32
-    shapes = (carry, _sds((k,), jnp.int64, one_chip),
-              _sds((k,), jnp.bool_, one_chip), _sds((R, k), f64, one_chip),
-              _sds((R, k), i32, one_chip), _sds((R, k), f64, one_chip),
-              _sds((R, k), f64, one_chip), _sds((R, F), i32, one_chip))
+    R, k = 8, 4096
+    step_fn, shapes = _counted_stream(compiled_kernels, one_chip)
     _, ys = jax.eval_shape(step_fn, *shapes)
     assert len(ys) == 4 and (ys[3].shape, ys[3].dtype) == ((R, k), jnp.int32)
     hlo = step_fn.lower(*shapes).compile().as_text()
@@ -222,3 +239,18 @@ def test_drain_loop_has_no_emulated_64bit_division(one_chip,
     # 49 with the int32 index; the int64 one compiled to 77, 27 of
     # them the division
     assert len(body) <= 49 + 4, len(body)
+
+
+@pytest.mark.parametrize("build", [_counted_sweep, _counted_stream],
+                         ids=["sweep", "stream"])
+def test_placement_compiles_to_no_loop(one_chip, compiled_kernels, build):
+    """``place`` reads the chosen worker's warm-pool row without a loop:
+    a row indexed by a per-replication worker compiles on the chip to a
+    serial ``while`` of one trip per replication (op_name
+    ``place/gather``), the busiest operation of the sweep's step."""
+    fn, shapes = build(compiled_kernels, one_chip)
+    hlo = fn.lower(*shapes).compile().as_text()
+    loops = [line for lines in _computations(hlo).values() for line in lines
+             if " while(" in line
+             and re.search(r'op_name="[^"]*\bplace/', line)]
+    assert not loops, loops
