@@ -1,4 +1,4 @@
-"""Registry of the ten assigned architectures (+ shapes).
+"""Registry of the ten assigned architectures.
 
 ``get(name)`` returns the exact full-size config from the assignment
 table; ``get_smoke(name)`` a reduced same-family variant for CPU tests.
@@ -6,9 +6,6 @@ table; ``get_smoke(name)`` a reduced same-family variant for CPU tests.
 from __future__ import annotations
 
 from importlib import import_module
-
-from .shapes import (SHAPES, ShapeCfg, applicable, smoke_shape,  # noqa
-                     TRAIN_4K, PREFILL_32K, DECODE_32K, LONG_500K)
 
 _MODULES = {
     "rwkv6-3b": "rwkv6_3b",

@@ -13,7 +13,7 @@ def full() -> ModelCfg:
     return ModelCfg(
         name="chameleon-34b", family="dense",
         n_layers=48, d_model=8192, n_heads=64, n_kv_heads=8, head_dim=128,
-        d_ff=22016, vocab=65536, qk_norm=True, fsdp=True,
+        d_ff=22016, vocab=65536, qk_norm=True,
         frontend="vq_image_tokens",
     )
 
@@ -21,4 +21,4 @@ def full() -> ModelCfg:
 def smoke() -> ModelCfg:
     return dataclasses.replace(
         full(), n_layers=2, d_model=128, n_heads=4, n_kv_heads=2,
-        head_dim=32, d_ff=256, vocab=512, fsdp=False, remat="none")
+        head_dim=32, d_ff=256, vocab=512, remat="none")

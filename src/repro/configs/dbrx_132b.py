@@ -10,9 +10,7 @@ def full() -> ModelCfg:
         n_layers=40, d_model=6144, n_heads=48, n_kv_heads=8, head_dim=128,
         d_ff=10752, vocab=100352, rope_theta=5e5,
         moe=MoECfg(n_experts=16, top_k=4, d_ff_expert=10752),
-        fsdp=True,
-        # pure-bf16 params + fp32 moments: the 16 GB/chip budget at this
-        # scale (see EXPERIMENTS.md memory analysis)
+        # pure-bf16 params: the 16 GB/chip budget at this scale
         param_dtype="bfloat16",
     )
 
@@ -22,4 +20,4 @@ def smoke() -> ModelCfg:
         full(), n_layers=2, d_model=128, n_heads=4, n_kv_heads=2,
         head_dim=32, d_ff=128, vocab=512,
         moe=MoECfg(n_experts=4, top_k=2, d_ff_expert=128),
-        fsdp=False, remat="none")
+        remat="none")

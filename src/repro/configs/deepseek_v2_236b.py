@@ -18,9 +18,7 @@ def full() -> ModelCfg:
         moe=MoECfg(n_experts=160, top_k=6, d_ff_expert=1536, n_shared=2),
         mla=MLACfg(q_lora=1536, kv_lora=512, qk_nope=128, qk_rope=64,
                    v_dim=128),
-        fsdp=True,
-        # pure-bf16 params + fp32 moments: the 16 GB/chip budget at this
-        # scale (see EXPERIMENTS.md memory analysis)
+        # pure-bf16 params: the 16 GB/chip budget at this scale
         param_dtype="bfloat16",
     )
 
@@ -32,4 +30,4 @@ def smoke() -> ModelCfg:
         moe=MoECfg(n_experts=8, top_k=2, d_ff_expert=64, n_shared=1),
         mla=MLACfg(q_lora=64, kv_lora=32, qk_nope=32, qk_rope=16,
                    v_dim=32),
-        fsdp=False, remat="none")
+        remat="none")

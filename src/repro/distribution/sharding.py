@@ -2,16 +2,15 @@
 
 Model code never names physical mesh axes.  It annotates tensors with
 *logical* dimension names (``shard(x, "batch", "seq", "embed")``) and the
-launcher installs a :class:`ShardCtx` that maps logical names to physical
+caller installs a :class:`ShardCtx` that maps logical names to physical
 mesh axes.  Outside any context the annotations are no-ops, so the same
-model code runs single-device (smoke tests) and SPMD (dry-run/production)
-unchanged.
+model code runs single-device and SPMD unchanged.
 
 Logical axis vocabulary
 =======================
 
 ==============  ==========================================================
-``batch``       global batch — data parallel (``("pod","data")`` multi-pod)
+``batch``       global batch — data parallel
 ``seq``         sequence — unsharded by default; ``seq_kv`` may map to
                 ``data`` for long-context flash-decode merging
 ``embed``       d_model of activations — unsharded (activations replicate)
@@ -42,10 +41,7 @@ AxisVal = Any  # str | tuple[str, ...] | None
 class ShardCtx:
     mesh: Mesh
     rules: Mapping[str, AxisVal]
-    # physical axis names for collectives (shard_map paths)
-    dp_axes: tuple[str, ...] = ("data",)
-    tp_axis: str = "model"
-    pod_axis: str | None = None
+    tp_axis: str = "model"       # physical axis for shard_map collectives
 
     def axis_size(self, logical: str) -> int:
         phys = self.rules.get(logical)
@@ -96,18 +92,6 @@ def shard(x: jax.Array, *logical: str | None) -> jax.Array:
         x, NamedSharding(ctx.mesh, spec))
 
 
-def named_sharding(*logical: str | None) -> NamedSharding | None:
-    ctx = _ctx.get()
-    if ctx is None:
-        return None
-    return NamedSharding(ctx.mesh, pspec(*logical))
-
-
-def tp_size() -> int:
-    ctx = _ctx.get()
-    return 1 if ctx is None else ctx.mesh.shape[ctx.tp_axis]
-
-
 def axis_size(logical: str) -> int:
     """Product of mesh-axis sizes a logical name maps to (1 w/o ctx)."""
     ctx = _ctx.get()
@@ -131,61 +115,33 @@ def phys(*logical: str) -> tuple | None:
     return tuple(axes) if axes else None
 
 
-def dp_size() -> int:
-    ctx = _ctx.get()
-    if ctx is None:
-        return 1
-    n = 1
-    for a in ctx.dp_axes:
-        n *= ctx.mesh.shape[a]
-    return n
-
-
 # ---------------------------------------------------------------------------
 # Rules construction
 # ---------------------------------------------------------------------------
 
-def make_rules(*, multi_pod: bool = False, fsdp: bool = False,
-               shard_heads: bool = True, shard_kv_heads: bool = True,
-               seq_kv_data: bool = False) -> dict[str, AxisVal]:
-    """Standard logical→physical rules for the production meshes.
+def make_rules() -> dict[str, AxisVal]:
+    """Standard logical→physical rules for a ``("data", "model")`` mesh.
 
-    ``fsdp`` additionally shards a designated parameter dim over the data
-    axis (ZeRO-3) for the ≥14 B archs.  ``shard_heads=False`` keeps
-    attention replicated over the model axis (archs whose head count does
-    not divide the TP degree and whose attention is a small param
-    fraction, e.g. gemma-2b with 8 heads).  ``seq_kv_data=True`` maps the
-    KV-cache sequence dim onto the data axis (long-context flash-decode).
+    ``fsdp``, ``expert_ff``, ``seq_kv`` and ``act_seq`` map to no axis;
+    a caller that wants ZeRO-3 parameter sharding, MoE expert weights
+    sharded on their ff dim, a data-sharded decode-cache sequence dim or
+    sequence-parallel residual activations sets them in the returned
+    dict.
     """
-    dp: AxisVal = ("pod", "data") if multi_pod else ("data",)
-    rules: dict[str, AxisVal] = {
-        "batch": dp,
+    return {
+        "batch": ("data",),
         "seq": None,
         "embed": None,
-        "heads": "model" if shard_heads else None,
-        "kv_heads": "model" if (shard_heads and shard_kv_heads) else None,
+        "heads": "model",
+        "kv_heads": "model",
         "ff": "model",
         "vocab": "model",
         "expert": "model",
         "tokens_tp": "model",
         "state": "model",
-        "fsdp": "data" if fsdp else None,
-        # serving layout for MoE decode: expert weights sharded on the
-        # per-expert ff dim over 'data' (no per-layer FSDP weight
-        # all-gather on the latency path); launcher enables per-shape.
+        "fsdp": None,
         "expert_ff": None,
-        "seq_kv": "data" if seq_kv_data else None,
+        "seq_kv": None,
         "seq_kv_tp": "model",    # decode-cache seq dim when kv_heads ∤ TP
-        # Megatron-style sequence parallelism: residual-stream activations
-        # (the values remat saves at layer boundaries) are sharded over
-        # the model axis; enabled per-shape by the launcher.
         "act_seq": None,
     }
-    return rules
-
-
-def param_sharding_tree(param_specs, mesh: Mesh):
-    """Map a pytree of PartitionSpec to NamedSharding."""
-    return jax.tree.map(
-        lambda s: NamedSharding(mesh, s),
-        param_specs, is_leaf=lambda s: isinstance(s, P))

@@ -1,9 +1,7 @@
 """Replication-axis device sharding for the streaming simulator.
 
-The training side maps *logical tensor dims* onto mesh axes
-(:mod:`repro.distribution.sharding`); the simulator's unit of
-parallelism is coarser — whole replications (seeds / policy-sweep
-cells) are independent, so the batched carry of
+The simulator's unit of parallelism is the replication: replications
+(seeds / policy-sweep cells) are independent, so the batched carry of
 :func:`repro.core.streaming.simulate_stream` simply splits its leading
 ``R`` axis across a 1-D ``"rep"`` mesh
 (:func:`repro.launch.mesh.make_rep_mesh`).  Every leaf of the carry and

@@ -1,15 +1,14 @@
 """Attention: GQA/MQA (+qk-norm), MLA, prefill and KV-cache decode.
 
-Three interchangeable implementations of causal self-attention, selected
+Four interchangeable implementations of causal self-attention, selected
 by ``cfg.attn_impl``:
 
 * ``xla_chunked`` — q-block scan with an inner dynamic ``fori_loop`` over
   KV blocks up to the causal frontier (flash-attention-style online
-  softmax, O(block) memory, no upper-triangle compute).  Used for the
-  full-config dry-run compiles (memory analysis) and real runs.
-* ``naive`` — full [S,S] score matrix.  Small models / tests / and the
-  *roofline* compiles, where loop bodies must be visible to XLA's cost
-  analysis (while-loop bodies are counted once; see launch/dryrun.py).
+  softmax, O(block) memory, no upper-triangle compute).  The default.
+* ``naive`` — full [S,S] score matrix.  Small models and tests.
+* ``xla_unrolled`` — Python-loop flash attention, for compiles whose
+  FLOPs XLA's cost analysis must see: it counts a while-loop body once.
 * ``pallas`` — the TPU flash-attention kernel in ``repro.kernels``
   (validated under ``interpret=True`` on CPU).
 
@@ -163,8 +162,8 @@ def _sdpa_chunked(q, k, v, chunk: int):
 def _sdpa_unrolled(q, k, v, chunk: int):
     """Python-loop flash attention: every (q,kv) block pair is a distinct
     HLO op, so XLA's cost analysis counts the true causal FLOPs
-    (while-loop bodies are counted once — this impl exists for the
-    roofline pass).  Upper-triangle block pairs are skipped at trace time.
+    (while-loop bodies are counted once).  Upper-triangle block pairs are
+    skipped at trace time.
     """
     B, S, H, Dh = q.shape
     KV = k.shape[2]
@@ -205,8 +204,8 @@ def _gqa_tp_pad(cfg, q, k, v):
 
     When ``H % TP != 0`` (e.g. qwen3's 40 heads on a 16-way model axis),
     GSPMD falls back to "involuntary full rematerialization" — it
-    replicates head-sharded tensors at every transition, which the
-    roofline measured as TB-scale collective+copy traffic.  Instead we
+    replicates head-sharded tensors at every transition: TB-scale
+    collective+copy traffic at full size.  Instead we
     make the head dim divisible: each of the KV heads is replicated
     ``rep = TP/KV`` times and its query group padded to ``rep·⌈G/rep⌉``
     — group-to-KV mapping is preserved, padded heads are sliced off
@@ -535,7 +534,8 @@ def _sdpa_chunked_vd(q, k, v, chunk, scale):
 
 
 def _sdpa_unrolled_vd(q, k, v, chunk, scale):
-    """Unrolled (trace-time loop) MLA SDPA — roofline-visible FLOPs."""
+    """Unrolled (trace-time loop) MLA SDPA — FLOPs visible to XLA's cost
+    analysis."""
     B, S, H, _ = q.shape
     Dv = v.shape[-1]
     qc = min(chunk, S)

@@ -84,8 +84,7 @@ class ModelCfg:
                        "pallas"] = "xla_chunked"
     attn_chunk: int = 512             # KV block for chunked attention
     remat: Literal["none", "full", "dots"] = "full"
-    # -- sharding hints (consumed by distribution.rules_for) ----------------
-    fsdp: bool = False                # ZeRO-3 param sharding over data axis
+    # -- sharding hints -----------------------------------------------------
     shard_heads: bool = True          # False when heads % TP != 0 everywhere
     # perf toggles (True = optimized path; False reproduces the baseline
     # lowering for the §Perf before/after attribution)
@@ -112,7 +111,7 @@ class ModelCfg:
         return jnp.dtype(self.param_dtype)
 
     def n_params(self) -> int:
-        """Exact parameter count (used for 6·N·D roofline bookkeeping)."""
+        """Exact parameter count."""
         D, F, V, L = self.d_model, self.d_ff, self.vocab, self.n_layers
         emb = V * D * (1 if self.tie_embeddings else 2)
         if self.family == "rwkv6":

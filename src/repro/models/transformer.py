@@ -7,23 +7,23 @@
 * ``forward(params, tokens) → (logits, aux)`` — full-sequence.
 * ``loss(params, tokens, labels) → scalar`` — mean xent + MoE aux.
 * ``init_cache / prefill / decode_step`` — serving path.
-* ``param_specs() → pytree[PartitionSpec]`` — logical shardings.
+* ``cache_specs(batch, max_len) → pytree[PartitionSpec]`` — decode-cache
+  shardings under the active sharding context.
 
-``layer_mode="unroll"`` replaces the scan with a Python loop — needed by
-the roofline pass, because XLA's cost analysis counts a while-loop body
-once (see launch/dryrun.py).
+``layer_mode="unroll"`` replaces the scan with a Python loop, for
+compiles whose FLOPs XLA's cost analysis must see: it counts a
+while-loop body once.
 """
 from __future__ import annotations
 
-import dataclasses
 from functools import partial
-from typing import Any, Callable, NamedTuple
+from typing import Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.distribution.sharding import current_ctx, pspec, shard
+from repro.distribution.sharding import pspec, shard
 from repro.models import attention as attn
 from repro.models import mamba2 as m2
 from repro.models import moe as moe_mod
@@ -42,7 +42,6 @@ class Model(NamedTuple):
     init_cache: Callable       # (batch, max_len) -> cache
     prefill: Callable          # (params, tokens, cache) -> (logits, cache)
     decode_step: Callable      # (params, tok[B,1], cache, pos[B]) -> (logits, cache)
-    param_specs: Callable      # () -> pytree of PartitionSpec
     cache_specs: Callable      # (batch, max_len) -> pytree of PartitionSpec
 
 
@@ -316,7 +315,6 @@ def _build_dense(cfg: ModelCfg, layer_mode: str) -> Model:
         return lm_logits(cfg, params["embed"], x), cache
 
     return Model(cfg, init, forward, loss, init_cache, prefill, decode_step,
-                 partial(_dense_specs, cfg),
                  partial(_dense_cache_specs, cfg))
 
 
@@ -377,7 +375,7 @@ def _build_rwkv(cfg: ModelCfg, layer_mode: str) -> Model:
         return lm_logits(cfg, params["embed"], x), cache
 
     return Model(cfg, init, forward, loss, init_cache, prefill, decode_step,
-                 partial(_rwkv_specs, cfg), partial(_rwkv_cache_specs, cfg))
+                 partial(_rwkv_cache_specs, cfg))
 
 
 # -- zamba2 hybrid ----------------------------------------------------------
@@ -547,56 +545,12 @@ def _build_hybrid(cfg: ModelCfg, layer_mode: str) -> Model:
         return lm_logits(cfg, params["embed"], x), cache
 
     return Model(cfg, init, forward, loss, init_cache, prefill, decode_step,
-                 partial(_hybrid_specs, cfg),
                  partial(_hybrid_cache_specs, cfg))
 
 
 # ---------------------------------------------------------------------------
-# Parameter / cache PartitionSpecs (logical → physical via active rules)
+# Decode-cache PartitionSpecs (logical → physical via active rules)
 # ---------------------------------------------------------------------------
-
-def _sp(*logical):
-    return pspec(*logical)
-
-
-def _dense_specs(cfg) -> dict:
-    attn_specs = (
-        {"wq_a": _sp("fsdp", None), "q_a_norm": _sp(None),
-         "wq_b": _sp(None, "ff"), "wkv_a": _sp("fsdp", None),
-         "kv_a_norm": _sp(None), "wk_b": _sp(None, "ff"),
-         "wv_b": _sp(None, "ff"), "wo": _sp("ff", "fsdp")}
-        if cfg.mla is not None else
-        {k: v for k, v in {
-            "wq": _sp("fsdp", "ff"), "wk": _sp("fsdp", "ff"),
-            "wv": _sp("fsdp", "ff"), "wo": _sp("ff", "fsdp"),
-            "q_norm": _sp(None), "k_norm": _sp(None)}.items()
-         if not (k in ("q_norm", "k_norm") and not cfg.qk_norm)})
-    if cfg.moe is not None:
-        mlp_specs = {"router": _sp(None, None),
-                     "w_gate": _sp("expert", "fsdp", "expert_ff"),
-                     "w_in": _sp("expert", "fsdp", "expert_ff"),
-                     "w_out": _sp("expert", "expert_ff", "fsdp")}
-        if cfg.moe.n_shared > 0:
-            mlp_specs["shared"] = {"w_gate": _sp("fsdp", "ff"),
-                                   "w_in": _sp("fsdp", "ff"),
-                                   "w_out": _sp("ff", "fsdp")}
-    elif cfg.mlp in ("swiglu", "geglu"):
-        mlp_specs = {"w_gate": _sp("fsdp", "ff"), "w_in": _sp("fsdp", "ff"),
-                     "w_out": _sp("ff", "fsdp")}
-    else:
-        mlp_specs = {"w_in": _sp("fsdp", "ff"), "w_out": _sp("ff", "fsdp")}
-    layer = {"attn": attn_specs, "mlp": mlp_specs}
-    if cfg.norm == "rmsnorm":
-        layer["ln1s"] = _sp(None)
-        layer["ln2s"] = _sp(None)
-    layer = jax.tree.map(lambda s: P(None, *s), layer,
-                         is_leaf=lambda s: isinstance(s, P))
-    emb = {"tok": _sp("vocab", None)}
-    if not cfg.tie_embeddings:
-        emb["lm_head"] = _sp(None, "vocab")
-    return {"embed": emb, "layers": layer,
-            "final_norm": _sp(None) if cfg.norm == "rmsnorm" else _sp(None)}
-
 
 def _dense_cache_specs(cfg, batch=None, max_len=None):
     """Decode-cache shardings, divisibility-aware.
@@ -605,8 +559,8 @@ def _dense_cache_specs(cfg, batch=None, max_len=None):
     shard the cache *sequence* dim over the model axis instead (decode
     attention then executes as a flash-decode: per-shard partial softmax
     merged by GSPMD's reduction).  MLA's latent cache has no head dim —
-    it always seq-shards.  ``seq_kv`` (data axis) is added for the
-    long-context shapes.
+    it always seq-shards.  ``seq_kv`` (data axis) is added when the
+    rules map it.
     """
     from repro.distribution.sharding import axis_size, phys
     if cfg.mla is not None:
@@ -627,28 +581,6 @@ def _dense_cache_specs(cfg, batch=None, max_len=None):
             "v": P(None, b, seq, kv, None)}
 
 
-def _rwkv_specs(cfg) -> dict:
-    tm = {"mu_x": _sp(None), "mu": _sp(None, None),
-          "mix_w1": _sp(None, None), "mix_w2": _sp(None, None, None),
-          "wr": _sp("fsdp", "ff"), "wk": _sp("fsdp", "ff"),
-          "wv": _sp("fsdp", "ff"), "wg": _sp("fsdp", "ff"),
-          "wo": _sp("ff", "fsdp"),
-          "decay_base": _sp(None), "decay_w1": _sp(None, None),
-          "decay_w2": _sp(None, None), "bonus": _sp(None),
-          "ln_scale": _sp(None), "ln_bias": _sp(None)}
-    cm = {"mu_k": _sp(None), "mu_r": _sp(None),
-          "wk": _sp("fsdp", "ff"), "wv": _sp("ff", "fsdp"),
-          "wr": _sp("fsdp", "ff")}
-    layer = jax.tree.map(lambda s: P(None, *s),
-                         {"tm": tm, "cm": cm, "ln1": _sp(None),
-                          "ln2": _sp(None)},
-                         is_leaf=lambda s: isinstance(s, P))
-    emb = {"tok": _sp("vocab", None)}
-    if not cfg.tie_embeddings:
-        emb["lm_head"] = _sp(None, "vocab")
-    return {"embed": emb, "layers": layer, "final_norm": _sp(None)}
-
-
 def _rwkv_cache_specs(cfg, batch=None, max_len=None):
     from repro.distribution.sharding import axis_size, phys
     H = cfg.d_model // cfg.rwkv.head_size
@@ -658,27 +590,6 @@ def _rwkv_cache_specs(cfg, batch=None, max_len=None):
             "cm_shift": P(None, b, None),
             "wkv": P(None, b, "model" if h_ok and axis_size("heads") > 1
                      else None, None, None)}
-
-
-def _hybrid_specs(cfg) -> dict:
-    m = {"in_proj": _sp("fsdp", "ff"), "conv_w": _sp(None, None),
-         "conv_b": _sp(None), "a_log": _sp(None), "d_skip": _sp(None),
-         "dt_bias": _sp(None), "norm_scale": _sp(None),
-         "out_proj": _sp("ff", "fsdp")}
-    layer = jax.tree.map(lambda s: P(None, *s),
-                         {"m": m, "ln": _sp(None)},
-                         is_leaf=lambda s: isinstance(s, P))
-    shared = {"ln1": _sp(None), "ln2": _sp(None),
-              "attn": {"wq": _sp("fsdp", "ff"), "wk": _sp("fsdp", "ff"),
-                       "wv": _sp("fsdp", "ff"), "wo": _sp("ff", "fsdp")},
-              "mlp": {"w_gate": _sp("fsdp", "ff"),
-                      "w_in": _sp("fsdp", "ff"),
-                      "w_out": _sp("ff", "fsdp")}}
-    emb = {"tok": _sp("vocab", None)}
-    if not cfg.tie_embeddings:
-        emb["lm_head"] = _sp(None, "vocab")
-    return {"embed": emb, "layers": layer, "shared": shared,
-            "final_norm": _sp(None)}
 
 
 def _hybrid_cache_specs(cfg, batch=None, max_len=None):

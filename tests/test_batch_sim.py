@@ -213,9 +213,9 @@ def test_summarize_batch_confidence_intervals():
 
 # The heaviest carry the engines support: carried-state balancer (DD) +
 # hybrid-histogram keep-alive + telemetry sketches + two-generation
-# fleet + TARGET_P99 autoscaler.  If chunking is bit-equal here, every
-# lighter combination is covered by construction (the chunk step shares
-# its arrival/completion bodies with the monolithic scan).
+# fleet + TARGET_P99 autoscaler, over several chunk sizes.  Every
+# balancer × scheduler and keep-alive preset is checked at one chunk size
+# in test_stream_parity.py.
 def _stream_cluster():
     from repro.core import LifecycleCfg
     from repro.fleet import FleetCfg

@@ -22,51 +22,6 @@ def get_f32(name):
 """
 
 
-def test_sharded_train_matches_single_device(devices_script):
-    out = devices_script(COMMON + """
-from repro.training.optimizer import OptCfg
-from repro.training.train import init_train_state, build_train_step
-from repro.data.pipeline import random_batch
-
-cfg = get_f32("olmo-1b")
-model = build_model(cfg)
-ocfg = OptCfg(lr=1e-2, warmup_steps=2, total_steps=10)
-tokens, labels = random_batch(0, 4, 32, cfg.vocab)
-tokens, labels = jnp.asarray(tokens), jnp.asarray(labels)
-
-# single-device reference
-state0 = init_train_state(model, jax.random.key(0))
-step0 = jax.jit(build_train_step(model, ocfg))
-s_ref = state0
-for i in range(3):
-    s_ref, m_ref = step0(s_ref, tokens, labels)
-
-# sharded
-mesh = make_test_mesh((2, 2), ("data", "model"))
-rules = make_rules()
-ctx = ShardCtx(mesh=mesh, rules=rules)
-with sharding_ctx(ctx):
-    model_s = build_model(cfg)
-    state = init_train_state(model_s, jax.random.key(0))
-    specs = model_s.param_specs()
-    sh = jax.tree.map(lambda s: NamedSharding(mesh, s), specs,
-                      is_leaf=lambda s: isinstance(s, P))
-    params = jax.tree.map(jax.device_put, state.params, sh)
-    state = state._replace(params=params)
-    step = jax.jit(build_train_step(model_s, ocfg))
-    for i in range(3):
-        state, m = step(state, tokens, labels)
-
-print("loss_ref", float(m_ref["loss"]), "loss_sharded", float(m["loss"]))
-assert abs(float(m_ref["loss"]) - float(m["loss"])) < 2e-3
-for a, b in zip(jax.tree.leaves(s_ref.params), jax.tree.leaves(state.params)):
-    np.testing.assert_allclose(np.asarray(a, np.float32),
-                               np.asarray(b, np.float32), rtol=1e-3, atol=1e-3)
-print("OK")
-""", n_devices=4)
-    assert "OK" in out
-
-
 def test_moe_ep_matches_dense_on_mesh(devices_script):
     out = devices_script(COMMON + """
 import dataclasses
@@ -91,81 +46,6 @@ print("aux", float(aux), float(aux_ref))
 assert abs(float(aux) - float(aux_ref)) < 1e-3
 print("OK")
 """, n_devices=4)
-    assert "OK" in out
-
-
-def test_compressed_grad_sync_close_to_exact(devices_script):
-    out = devices_script(COMMON + """
-from repro.training.optimizer import OptCfg
-from repro.training.train import (init_train_state, build_train_step,
-                                  build_train_step_compressed)
-from repro.data.pipeline import random_batch
-
-cfg = get_f32("olmo-1b")
-ocfg = OptCfg(lr=5e-3, warmup_steps=2, total_steps=20)
-tokens, labels = random_batch(0, 4, 32, cfg.vocab)
-tokens, labels = jnp.asarray(tokens), jnp.asarray(labels)
-
-mesh = make_test_mesh((2, 2, 2), ("pod", "data", "model"))
-rules = make_rules(multi_pod=True)
-ctx = ShardCtx(mesh=mesh, rules=rules, dp_axes=("pod", "data"),
-               pod_axis="pod")
-with sharding_ctx(ctx):
-    model = build_model(cfg)
-    state = init_train_state(model, jax.random.key(0), compressed=True)
-    step_c = jax.jit(build_train_step_compressed(model, ocfg))
-    step_e = jax.jit(build_train_step(model, ocfg))
-    se = state._replace(err=None)
-    losses_c, losses_e = [], []
-    sc = state
-    for i in range(5):
-        sc, mc = step_c(sc, tokens, labels)
-        se, me = step_e(se, tokens, labels)
-        losses_c.append(float(mc["loss"]))
-        losses_e.append(float(me["loss"]))
-print("compressed", losses_c)
-print("exact     ", losses_e)
-assert losses_c[-1] < losses_c[0]       # converging
-assert abs(losses_c[-1] - losses_e[-1]) < 0.25
-print("OK")
-""", n_devices=8)
-    assert "OK" in out
-
-
-def test_elastic_remesh_checkpoint(devices_script):
-    out = devices_script(COMMON + """
-import tempfile
-from repro.training.checkpoint import CheckpointManager
-from repro.training.train import init_train_state
-
-cfg = configs.get_smoke("gemma-2b")
-mesh_a = make_test_mesh((2, 4), ("data", "model"))
-ctx_a = ShardCtx(mesh=mesh_a, rules=make_rules())
-with sharding_ctx(ctx_a):
-    model = build_model(cfg)
-    state = init_train_state(model, jax.random.key(0))
-    specs = model.param_specs()
-    sh = jax.tree.map(lambda s: NamedSharding(mesh_a, s), specs,
-                      is_leaf=lambda s: isinstance(s, P))
-    params = jax.tree.map(jax.device_put, state.params, sh)
-
-with tempfile.TemporaryDirectory() as d:
-    mgr = CheckpointManager(d)
-    mgr.save(params, 1, blocking=True)
-    # restore onto a DIFFERENT mesh shape (elastic re-mesh)
-    mesh_b = make_test_mesh((4, 2), ("data", "model"))
-    ctx_b = ShardCtx(mesh=mesh_b, rules=make_rules())
-    with sharding_ctx(ctx_b):
-        model_b = build_model(cfg)
-        sh_b = jax.tree.map(lambda s: NamedSharding(mesh_b, s),
-                            model_b.param_specs(),
-                            is_leaf=lambda s: isinstance(s, P))
-        restored, step = mgr.restore(params, sharding_tree=sh_b)
-    for a, b in zip(jax.tree.leaves(params), jax.tree.leaves(restored)):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-        assert b.sharding.mesh.shape["data"] == 4
-print("OK")
-""", n_devices=8)
     assert "OK" in out
 
 
@@ -199,22 +79,4 @@ np.testing.assert_allclose(np.asarray(dec_ref, np.float32),
                            rtol=1e-3, atol=1e-3)
 print("OK")
 """, n_devices=8)
-    assert "OK" in out
-
-
-def test_dryrun_single_cell_production_mesh(devices_script):
-    """One real dry-run cell on the 16x16 production mesh (512 fake
-    devices would be the multi-pod pass; single-pod = 256 suffices to
-    prove the pipeline inside pytest — the full sweep is a deliverable
-    run separately)."""
-    out = devices_script("""
-import os
-os.environ["XLA_FLAGS"] = os.environ.get("XLA_FLAGS", "")
-from repro.launch.dryrun import run_cell
-r = run_cell("olmo-1b", "decode_32k", multi_pod=False)
-assert r.status == "ok", r.reason
-assert r.peak_memory_bytes < 16 * 2**30
-assert r.flops > 0
-print("OK", r.flops, r.peak_memory_bytes)
-""", n_devices=512, timeout=560)
     assert "OK" in out
