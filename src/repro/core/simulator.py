@@ -251,6 +251,20 @@ def _row(plane: jax.Array, w) -> jax.Array:
     return row
 
 
+def _hit(shape, w, s) -> jax.Array:
+    """One-hot ``[W, n]`` mask of element ``(w, s)``: the write counterpart
+    of :func:`_row`.
+
+    ``jnp.where(_hit(...) & mask, value, plane)`` is
+    ``plane.at[w, s].set(jnp.where(mask, value, plane[w, s]))`` bit for
+    bit.  Under ``vmap`` with an element per replication, the indexed
+    write compiles on the TPU to a scatter serial over the replications;
+    the select fuses into the passes the plane already takes.
+    """
+    return ((jnp.arange(shape[0], dtype=jnp.int32) == w)[:, None]
+            & (jnp.arange(shape[1], dtype=jnp.int32) == s)[None, :])
+
+
 def _build_engine(policy: PolicySpec, cluster: ClusterCfg,
                   n_arrivals: int, n_functions: int,
                   backend: str = "jax",
@@ -505,10 +519,10 @@ def _build_engine(policy: PolicySpec, cluster: ClusterCfg,
         slot (the ``argmin`` of time-to-done).  Simultaneous completions
         drain in successive zero-``tau`` iterations; their bookkeeping
         (response writes to distinct indices, warm-pool increments)
-        commutes, so results are identical to batch-completing them while
-        the per-iteration update touches O(1) state instead of scattering
-        over the whole ``[W, S]`` matrix (the engine's hot path at large
-        ``W``).
+        commutes, so results are identical to batch-completing them.  The
+        slot is retired by one-hot selects (:func:`_hit`), not scatters:
+        under ``vmap`` the loop already selects every carried plane after
+        each iteration, and the writes fuse into that pass.
 
         Returns ``(st, iterations)``: the loop's iteration count (int32)
         with ``count_drain``, else ``None``.
@@ -557,7 +571,7 @@ def _build_engine(policy: PolicySpec, cluster: ClusterCfg,
                                            st.q_tail - st.q_head)
             now = st.now + tau
             remaining = st.remaining - rates * tau
-            # complete the argmin slot only (idx N / col F are scratch);
+            # complete the argmin slot only (resp idx N is scratch);
             # the remaining<=EPS clause matches cond's pending drain — a
             # task left within EPS of done at the window edge completes
             # here (as both the old batch-done engine and the oracle do)
@@ -598,10 +612,10 @@ def _build_engine(policy: PolicySpec, cluster: ClusterCfg,
                 # window of the completion time
                 tl = tln_engine.on_complete(tl, now, resp_val, svc_nom,
                                             completed, tl_edges)
-            w_pad = jnp.where(completed, wj, 0)
-            f_pad = jnp.where(completed, f_j, F)
             life = st.life
             if life_on:
+                w_pad = jnp.where(completed, wj, 0)
+                f_pad = jnp.where(completed, f_j, F)
                 with jax.named_scope("lifecycle"):
                     # mirror LifecycleRuntime.on_complete: zero a stale
                     # pool before the increment (expired executors must not
@@ -633,14 +647,13 @@ def _build_engine(policy: PolicySpec, cluster: ClusterCfg,
                             tel = tel_engine.on_evict(tel, over)
                         if tl_on:
                             tl = tln_engine.on_evict(tl, now, over)
+                warm = warm.at[:, F].set(0)
             else:
-                warm = st.warm.at[w_pad, f_pad].add(
-                    completed.astype(jnp.int32))
-            warm = warm.at[:, F].set(0)
-            remaining = remaining.at[wj, sj].set(
-                jnp.where(completed, jnp.inf, remaining[wj, sj]))
-            task_idx = st.task_idx.at[wj, sj].set(
-                jnp.where(completed, jnp.int32(-1), tid))
+                warm = st.warm + (_hit(st.warm.shape, wj, f_j)
+                                  & completed).astype(jnp.int32)
+            done = _hit(remaining.shape, wj, sj) & completed
+            remaining = jnp.where(done, jnp.inf, remaining)
+            task_idx = jnp.where(done, jnp.int32(-1), st.task_idx)
             lb = st.lb
             if stateful:
                 # one hook call per completion, masked branch-free: the

@@ -154,3 +154,59 @@ def test_row_read_matches_indexing_per_replication(case):
     want = plane[np.arange(len(ws)), ws]
     assert got.dtype == want.dtype
     np.testing.assert_array_equal(got.view(np.uint8), want.view(np.uint8))
+
+
+def _slot_plane_f64():
+    plane = np.random.default_rng(1).normal(size=(4, 3, 5)) * 1e3
+    plane[0, 1] = [np.inf, -0.0, 0.0, 5e-324, -1e-310]
+    plane[1, 2] = -0.0
+    plane[2, 0] = [-np.inf, 4e-320, -0.0, np.inf, 1e300]
+    plane[3] = 5e-324
+    return plane, np.array([1, 2, 0, 2]), np.array([1, 0, 1, 4])
+
+
+def _slot_plane_i32():
+    plane = np.arange(4 * 3 * 5, dtype=np.int32).reshape(4, 3, 5) - 30
+    plane[:, :, 4] = -1
+    return plane, np.array([0, 2, 1, 2]), np.array([4, 0, 3, 4])
+
+
+@pytest.mark.parametrize("mask", [[True] * 4, [True, False, True, False],
+                                  [False] * 4],
+                         ids=["all", "some", "none"])
+@pytest.mark.parametrize("case,value", [
+    (_slot_plane_f64, np.inf), (_slot_plane_f64, -0.0),
+    (_slot_plane_i32, -1), (_slot_plane_i32, None)],
+    ids=["f64-set-inf", "f64-set-neg-zero", "i32-set-free", "i32-add"])
+def test_one_hot_write_matches_indexed_write_per_replication(case, value,
+                                                             mask):
+    """The drain retires the completing slot by a select on a one-hot
+    mask (an add of it, for the warm pool); under ``vmap``, with another
+    slot and mask per replication, it writes what the indexed write
+    wrote and leaves every other element (inf, -0.0, subnormals) as it
+    was, bit for bit."""
+    import jax
+    import jax.numpy as jnp
+    from repro.core.simulator import _hit
+    plane, ws, ss = case()
+    mask = np.array(mask)
+
+    def one_hot(p, w, s, m):
+        hit = _hit(p.shape, w, s) & m
+        if value is None:
+            return p + hit.astype(p.dtype)
+        return jnp.where(hit, jnp.asarray(value, p.dtype), p)
+
+    def indexed(p, w, s, m):
+        if value is None:
+            return p.at[w, s].add(m.astype(p.dtype))
+        return p.at[w, s].set(jnp.where(m, jnp.asarray(value, p.dtype),
+                                        p[w, s]))
+
+    got = np.asarray(jax.vmap(one_hot)(plane, ws, ss, mask))
+    want = np.asarray(jax.vmap(indexed)(plane, ws, ss, mask))
+    assert got.dtype == want.dtype == plane.dtype
+    np.testing.assert_array_equal(got.view(np.uint8), want.view(np.uint8))
+    kept = ~mask
+    np.testing.assert_array_equal(got[kept].view(np.uint8),
+                                  plane[kept].view(np.uint8))

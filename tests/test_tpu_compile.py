@@ -236,9 +236,36 @@ def test_drain_loop_has_no_emulated_64bit_division(one_chip,
         _sds((R, F), i32, one_chip)).compile().as_text()
     body = _drain_body(hlo)
     assert not [line for line in body if "floor_divide" in line]
-    # 49 with the int32 index; the int64 one compiled to 77, 27 of
-    # them the division
-    assert len(body) <= 49 + 4, len(body)
+    # 39 with the slot retired by one-hot selects, 49 with its scatters;
+    # the int64 index compiled to 77, 27 of them the division
+    assert len(body) <= 39 + 4, len(body)
+
+
+@pytest.mark.parametrize("build,planes", [
+    (_counted_sweep, {(32, 8, 96), (32, 8, 41)}),
+    (_counted_stream, {(8, 100, 96), (8, 100, 41)})],
+    ids=["sweep", "stream"])
+def test_drain_retires_without_slot_scatters(one_chip, compiled_kernels,
+                                             build, planes):
+    """The drain retires the completing slot of ``remaining``,
+    ``task_idx`` and ``warm`` (``[R, W, S]`` and ``[R, W, F+1]``) by
+    one-hot selects: under ``vmap`` an indexed write compiles on the chip
+    to a scatter serial over the replications, among the busiest
+    operations of a drain iteration.  ``resp``'s ``[R, N+1]`` scatter and
+    ``tel``'s histogram scatter-adds stay."""
+    fn, shapes = build(compiled_kernels, one_chip)
+    body = _drain_body(fn.lower(*shapes).compile().as_text())
+    writes = [line for line in body if re.search(
+        r'op_name="[^"]*advance/while/body/[^"]*scatter(-add)?"', line)]
+    assert writes
+
+    def written(line):
+        out = re.search(r" = (.*?) [a-z][\w\-]*\(", line).group(1)
+        return {tuple(map(int, dims.split(",")))
+                for dims in re.findall(r"\[([\d,]+)\]", out)}
+    plane_writes = [line for line in writes if written(line) & planes]
+    assert not plane_writes, plane_writes
+    assert not [line for line in body if "dynamic-update-slice" in line]
 
 
 @pytest.mark.parametrize("build", [_counted_sweep, _counted_stream],
